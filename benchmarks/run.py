@@ -1,7 +1,7 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``.
 """Benchmark driver:
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig6,roofline,...]
-      [--cache-dir DIR] [--no-compile-cache]
+      [--no-compile-cache]
 
 Figure suites dispatch through the batched experiment engine
 (repro.core.experiment): each protocol's whole rate grid compiles once and
@@ -11,7 +11,7 @@ protocol, so only the first suite pays a trace.
 
 The persistent XLA compilation cache (repro.core.compile_cache) is enabled
 by default at the repo-local ``.jax_cache`` directory
-(``JAX_COMPILATION_CACHE_DIR`` or ``--cache-dir`` overrides), so a repeat
+(``JAX_COMPILATION_CACHE_DIR`` overrides), so a repeat
 run — another process, CI with the cache restored — skips XLA compilation
 entirely and pays only tracing.
 
@@ -142,9 +142,6 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="shorter sims (2s instead of 4s)")
     ap.add_argument("--only", default="")
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent compile-cache directory "
-                         "(default: repo-local .jax_cache)")
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="disable the persistent XLA compilation cache "
                          "(every process recompiles)")
@@ -158,7 +155,7 @@ def main() -> None:
     if args.no_compile_cache:
         compile_cache.disable()
     else:
-        cache_dir = compile_cache.enable(args.cache_dir)
+        cache_dir = compile_cache.enable()
         print(f"# persistent compile cache: {cache_dir}", file=sys.stderr)
 
     figures.ART.mkdir(parents=True, exist_ok=True)

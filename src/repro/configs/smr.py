@@ -78,7 +78,8 @@ class SMRConfig:
     delay_horizon_ticks: Union[int, str] = "auto"
     # Packed-channel-ring commit backend (repro.kernels.channel_ring):
     # "auto" = Pallas kernel on TPU, pure-jnp oracle elsewhere; also
-    # "jnp"/"ref", "pallas", "pallas-interpret" (parity testing).
+    # "jnp"/"ref", "pallas" (the compiled kernel, TPU only) and
+    # "pallas-interpret" (the kernel interpreted, for parity tests).
     channel_backend: str = "auto"
     # Flight recorder (repro.obs): "off" (default — the compiled program
     # is instruction-identical to an untraced build), "counters"

@@ -6,7 +6,8 @@ wall. This module makes compilation a **once-ever** cost and makes that
 claim *measurable*:
 
 1. ``enable()`` / ``ensure()`` pin the JAX persistent compilation cache to
-   a repo-local directory (``JAX_COMPILATION_CACHE_DIR`` overrides), with
+   ``JAX_COMPILATION_CACHE_DIR`` when it is set, else to a repo-local
+   directory — no caller places it anywhere else — with
    the size/compile-time thresholds dropped to zero so every sweep program
    is cached. Repeat processes — CI jobs, pytest re-runs, benchmark
    re-runs — then pay XLA compile once ever: the second process *traces*
@@ -121,12 +122,12 @@ def default_cache_dir() -> Path:
     return Path.home() / ".cache" / "mandator_repro_jax"
 
 
-def enable(cache_dir: Optional[os.PathLike | str] = None) -> Path:
-    """Enable the persistent compilation cache at ``cache_dir`` (default:
-    ``default_cache_dir()``). Idempotent; switching directories resets the
+def enable() -> Path:
+    """Enable the persistent compilation cache at ``default_cache_dir()``.
+    Idempotent; a changed ``JAX_COMPILATION_CACHE_DIR`` resets the
     in-memory cache handle so the new directory takes effect."""
     from jax._src import compilation_cache as _cc
-    path = Path(cache_dir) if cache_dir is not None else default_cache_dir()
+    path = default_cache_dir()
     path.mkdir(parents=True, exist_ok=True)
     changed = (not _state["enabled"]) or _state["dir"] != path
     jax.config.update("jax_enable_compilation_cache", True)
@@ -178,7 +179,7 @@ _fingerprint: Optional[str] = None
 
 def source_fingerprint() -> str:
     """Hash of everything that can invalidate a serialized program: the
-    jax/jaxlib versions, the backend platform, and the full source of
+    jax/jaxlib versions and the full source of
     ``src/repro`` (any edit to the simulator must rebuild programs — the
     blob captures the traced computation, not the Python that built it).
     Computed once per process (~milliseconds)."""
@@ -190,7 +191,6 @@ def source_fingerprint() -> str:
         h = hashlib.sha256()
         h.update(jax.__version__.encode())
         h.update(jaxlib.__version__.encode())
-        h.update(jax.default_backend().encode())
         root = Path(__file__).resolve().parents[1]  # src/repro
         for f in sorted(root.rglob("*.py")):
             h.update(str(f.relative_to(root)).encode())

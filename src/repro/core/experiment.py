@@ -71,6 +71,7 @@ from repro import workloads as wlc
 from repro.configs.smr import SMRConfig
 from repro.core import compile_cache, harness, netsim
 from repro.distributed import mesh as dmesh
+from repro.kernels.channel_ring import ops as ring_ops
 
 ANALYTIC_PROTOCOLS = ("epaxos", "rabia")
 
@@ -209,14 +210,15 @@ _PROGRAMS: Dict[str, "jax.stages.Wrapped"] = {}
 
 
 def _program_key(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
-                 args: tuple) -> str:
+                 platform: str, args: tuple) -> str:
     """Disk key of one canonical program: everything that shapes the
-    traced computation (protocol + cfg + workload-mode statics, the arg
-    pytree structure with shapes/dtypes) plus the source fingerprint —
-    editing any simulator source invalidates every stored program."""
+    traced computation (protocol + cfg + workload-mode statics, the
+    target platform, the arg pytree structure with shapes/dtypes) plus
+    the source fingerprint — editing any simulator source invalidates
+    every stored program."""
     import hashlib
     leaves, treedef = jax.tree.flatten(args)
-    parts = [protocol, repr(cfg), repr(mode),
+    parts = [protocol, repr(cfg), repr(mode), platform,
              compile_cache.source_fingerprint(), str(treedef)]
     parts += [f"{np.asarray(x).dtype}{np.asarray(x).shape}" for x in leaves]
     return hashlib.sha256("|".join(parts).encode()).hexdigest()[:32]
@@ -229,32 +231,29 @@ def _acquire_program(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
     ``jax.export`` blob — loading skips tracing AND lowering), and only
     as a last resort a fresh trace (which is then serialized for every
     future process). The XLA executable underneath is covered separately
-    by the persistent compilation cache."""
-    key = _program_key(protocol, cfg, mode, args)
+    by the persistent compilation cache. The program is exported for the
+    platform the dispatch runs on (``ring_ops.target_platform``), so a
+    process that runs on the TPU and on its host CPU keeps one of each."""
+    platform = ring_ops.target_platform()
+    key = _program_key(protocol, cfg, mode, platform, args)
     fn = _PROGRAMS.get(key)
     if fn is not None:
         return fn
     from jax import export as jax_export
     d = compile_cache.program_dir()
     path = d / f"{protocol}-{key}.bin" if d is not None else None
-    exp = None
     if path is not None and path.exists():
-        try:
-            exp = jax_export.deserialize(path.read_bytes())
-            # a loaded program counts as materialized, exactly like a
-            # fresh trace would — per-process accounting stays identical
-            # whether the store was warm or cold
-            _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
-        except Exception:
-            exp = None
-    if exp is None:
+        exp = jax_export.deserialize(path.read_bytes())
+        # a loaded program counts as materialized, exactly like a fresh
+        # trace would — per-process accounting stays identical whether
+        # the store was warm or cold
+        _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
+    else:
         f = jax.jit(partial(_sweep_body, protocol, cfg, mode))
-        exp = jax_export.export(f)(*args)  # traces once (body counts it)
+        # traces once (the body counts it)
+        exp = jax_export.export(f, platforms=(platform,))(*args)
         if path is not None:
-            try:
-                path.write_bytes(exp.serialize())
-            except OSError:
-                pass
+            path.write_bytes(exp.serialize())
     fn = jax.jit(exp.call)
     _PROGRAMS[key] = fn
     return fn
@@ -289,7 +288,6 @@ def _acquire_sharded(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
     fn = _SHARDED.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     def body(env_b, wl_b, rate_b, seed_b):
@@ -309,8 +307,8 @@ def _acquire_sharded(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
         return jax.lax.map(one, (env_b, wl_b, rate_b, seed_b))
 
     spec = PartitionSpec(dmesh.GRID_AXIS)
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                           check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                               out_specs=spec, check_vma=False))
     _SHARDED[key] = fn
     return fn
 
@@ -400,6 +398,20 @@ class PendingSweep:
         self._wl_names = wl_names
         self._outs = outs         # async device-array trees, one per chunk
         self._n_real = n_real     # sharded path: real points before padding
+
+    def point_devices(self) -> List[int]:
+        """Id of the device that holds each dispatched point's results,
+        padding rows of a sharded grid included (read before
+        ``collect()``)."""
+        ids: List[int] = []
+        for o in self._outs:
+            leaf = jax.tree.leaves(o)[0]
+            rows = [None] * leaf.shape[0]
+            for sh in leaf.addressable_shards:
+                for r in range(leaf.shape[0])[sh.index[0]]:
+                    rows[r] = sh.device.id
+            ids += rows
+        return ids
 
     def collect(self) -> List[Dict]:
         if self._results is not None:
@@ -540,18 +552,15 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
                    jax.tree.map(lambda x: x[i:i + 1], wl_b),
                    rate_b[i:i + 1], seed_b[i:i + 1])
                   for i in range(len(pts))]
-    fn = _sweep_compiled
     if canonical:
         # canonical programs additionally go through the on-disk program
         # store: warm processes deserialize the traced computation instead
         # of re-tracing it (the persistent XLA cache below then supplies
         # the executable)
-        try:
-            prog = _acquire_program(protocol, cfg, mode, chunks[0])
-            fn = lambda _p, _c, _m, *a: prog(*a)  # noqa: E731
-        except Exception:
-            fn = _sweep_compiled  # fall back to plain jit
-    outs = [fn(protocol, cfg, mode, *c) for c in chunks]
+        prog = _acquire_program(protocol, cfg, mode, chunks[0])
+        outs = [prog(*c) for c in chunks]
+    else:
+        outs = [_sweep_compiled(protocol, cfg, mode, *c) for c in chunks]
     dt = time.perf_counter() - t0
     stats = _TIMING.setdefault(protocol, {
         "compile_s": 0.0, "run_s": 0.0, "dispatches": 0, "horizon": 0})

@@ -3,11 +3,12 @@
 Called from ``core/channel.ring_commit`` inside the (already-jitted) tick
 scan, so there is no jit here — just backend selection and the reshaping
 each backend wants. The pure-jnp oracle (ref.py) is the CPU default and
-the correctness oracle; the Pallas kernel (kernel.py) is the TPU path and
-runs in interpret mode for parity tests.
+the correctness oracle; the Pallas kernel (kernel.py) is the TPU path.
 
-Backends: ``"jnp"`` (alias ``"ref"``), ``"pallas"``,
-``"pallas-interpret"``, ``"auto"`` (pallas on TPU, jnp elsewhere).
+Backends: ``"jnp"`` (alias ``"ref"``), ``"pallas"`` (the compiled kernel,
+TPU only), ``"pallas-interpret"`` (the kernel under the Pallas
+interpreter, for parity tests), ``"auto"`` (pallas on TPU, jnp
+elsewhere).
 """
 from __future__ import annotations
 
@@ -17,10 +18,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.channel_ring.kernel import EntryLayout, ring_commit_tpu
+from repro.kernels.channel_ring.kernel import ring_commit_tpu
 from repro.kernels.channel_ring.ref import ring_commit_ref
 
 BACKENDS = ("auto", "jnp", "ref", "pallas", "pallas-interpret")
+
+# static per-entry layout: (payload offset, width, flag field, additive)
+EntryLayout = Tuple[int, int, int, bool]
 
 # per-tick send entry, already mask-merged: (slot [n,n] int32,
 # vals [n,n,w] float32 with merge-neutral at masked-out links,
@@ -28,12 +32,21 @@ BACKENDS = ("auto", "jnp", "ref", "pallas", "pallas-interpret")
 Entry = Tuple[jax.Array, jax.Array, jax.Array]
 
 
+def target_platform() -> str:
+    """Platform that a program traced now runs on: the device set by
+    ``jax.default_device`` if any, else the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def resolve_backend(backend: str) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"unknown channel backend {backend!r}; "
                          f"one of {BACKENDS}")
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "pallas" if target_platform() == "tpu" else "jnp"
     return "jnp" if backend == "ref" else backend
 
 
@@ -74,6 +87,44 @@ def _scatter_args(entries: Sequence[Entry], layout: Sequence[EntryLayout]):
     return out + (None, None, None)
 
 
+def _planes(entries: Sequence[Entry], layout: Sequence[EntryLayout],
+            k: int):
+    """Expand the entries into full-width merge planes for the kernel:
+    ``(tgt [P, n, n, K] int32, val [P, n, n, K] f32, adds)``. Channels own
+    disjoint field windows, so the p-th send of every channel shares max
+    plane p; additive payloads share one add plane (one send per additive
+    channel per tick). Fields no send of a plane covers get target -1."""
+    max_planes: list = []        # per plane: {field offset: (slot, vals)}
+    add_plane: dict = {}
+    for (slot, vals, flag), (off, w, flag_off, additive) in zip(entries,
+                                                                layout):
+        if additive:
+            add_plane[off] = (slot, vals)
+            pieces = {flag_off: (slot, flag[..., None])}
+        else:
+            pieces = {off: (slot, jnp.concatenate([vals, flag[..., None]],
+                                                  axis=-1))}
+        for plane in max_planes:
+            if not set(pieces) & set(plane):
+                plane.update(pieces)
+                break
+        else:
+            max_planes.append(dict(pieces))
+    n = entries[0][0].shape[0]
+    tgts, vals = [], []
+    for plane in max_planes + ([add_plane] if add_plane else []):
+        tgt = jnp.full((n, n, k), -1, jnp.int32)
+        val = jnp.zeros((n, n, k), jnp.float32)
+        for off, (slot, v) in plane.items():
+            w = v.shape[-1]
+            tgt = tgt.at[..., off:off + w].set(slot[..., None])
+            val = val.at[..., off:off + w].set(v)
+        tgts.append(tgt)
+        vals.append(val)
+    adds = (False,) * len(max_planes) + ((True,) if add_plane else ())
+    return jnp.stack(tgts), jnp.stack(vals), adds
+
+
 def ring_commit(buf: jax.Array, t: jax.Array, fill: jax.Array,
                 entries: Sequence[Entry], layout: Sequence[EntryLayout],
                 backend: str = "auto") -> jax.Array:
@@ -83,9 +134,6 @@ def ring_commit(buf: jax.Array, t: jax.Array, fill: jax.Array,
     if backend == "jnp":
         return ring_commit_ref(buf, t, fill,
                                *_scatter_args(entries, layout))
-    interpret = (backend == "pallas-interpret"
-                 or jax.default_backend() != "tpu")
-    return ring_commit_tpu(buf, t, fill,
-                           [e[0] for e in entries], [e[1] for e in entries],
-                           [e[2] for e in entries], layout,
-                           interpret=interpret)
+    tgt, val, adds = _planes(entries, layout, buf.shape[-1])
+    return ring_commit_tpu(buf, t, fill, tgt, val, adds,
+                           interpret=backend == "pallas-interpret")

@@ -45,10 +45,9 @@ def _no_persistent_cache_marker(request):
         yield
         return
     was_enabled = compile_cache.enabled()
-    was_dir = compile_cache.cache_dir()
     compile_cache.disable()
     try:
         yield
     finally:
         if was_enabled:
-            compile_cache.enable(was_dir)
+            compile_cache.enable()

@@ -147,14 +147,15 @@ def test_compile_report_shape():
 # ------------------------------------------- persistent cache plumbing ----
 
 @pytest.mark.no_persistent_cache
-def test_enable_disable_and_counters(tmp_path):
-    """A fresh jit compiles into the pinned dir (miss); re-compiling the
-    same program after clearing the in-memory jit caches loads it back
-    (hit) instead of recompiling."""
+def test_enable_disable_and_counters(tmp_path, monkeypatch):
+    """A fresh jit compiles into the dir JAX_COMPILATION_CACHE_DIR names
+    (miss); re-compiling the same program after clearing the in-memory
+    jit caches loads it back (hit) instead of recompiling."""
     import jax
     import jax.numpy as jnp
 
-    compile_cache.enable(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    compile_cache.enable()
     try:
         assert compile_cache.enabled()
         assert compile_cache.cache_dir() == tmp_path
@@ -179,11 +180,12 @@ def test_enable_disable_and_counters(tmp_path):
 
 
 @pytest.mark.no_persistent_cache
-def test_ensure_respects_explicit_disable(tmp_path):
+def test_ensure_respects_explicit_disable(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     compile_cache.disable()
     assert compile_cache.ensure() is None, \
         "ensure() must not undo an explicit disable()"
-    compile_cache.enable(tmp_path)
+    compile_cache.enable()
     assert compile_cache.ensure() == tmp_path
     compile_cache.disable()
     assert not compile_cache.enabled()
@@ -201,12 +203,12 @@ def test_ensure_respects_env_opt_out(monkeypatch):
 # --------------------------------------- warm process compiles nothing ----
 
 _SWEEP_SCRIPT = """\
-import json, sys
+import json
 from repro.core import compile_cache, experiment
 from repro.configs.smr import SMRConfig
 from repro.core.experiment import SweepSpec, run_sweep
 
-compile_cache.enable(sys.argv[1])
+compile_cache.enable()
 cfg = SMRConfig(sim_seconds=0.4)
 res = run_sweep("mandator", cfg, SweepSpec(rates=(20_000, 60_000)))
 rep = experiment.compile_report()
@@ -231,9 +233,9 @@ def _run_sweep_subprocess(cache_dir: Path) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
     # scope the subprocess strictly to the pinned dir
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     out = subprocess.run(
-        [sys.executable, "-c", _SWEEP_SCRIPT, str(cache_dir)],
+        [sys.executable, "-c", _SWEEP_SCRIPT],
         capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, f"subprocess failed:\n{out.stderr}"
     return json.loads(out.stdout)

@@ -1,0 +1,108 @@
+"""Compile the main path for a described TPU v5e chip, with no chip.
+
+The TPU compiler is installed next to the CPU backend, and compiles for a
+topology that is described and not attached. It refuses what the Pallas
+interpreter accepts (scatters and unaligned lane slices in a kernel body,
+VMEM overruns), so these tests pin that the ring-commit kernel and one
+whole canonical sweep program lower to a ``tpu_custom_call`` for v5e.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every pytest worker
+imports this file.
+"""
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.smr import SMRConfig
+from repro.core import channel as ch
+from repro.core import experiment, paxos, sporades
+from repro.core.experiment import SweepSpec
+
+pytestmark = pytest.mark.no_persistent_cache
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(x, sharding):
+    return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
+                                sharding=sharding)
+
+
+def _compile_commit(spec: ch.RingSpec, d: int, n: int, sharding,
+                    names) -> str:
+    """Compile one tick's ring commit of ``names`` sends (a channel named
+    twice is sent twice) with the compiled Pallas kernel; returns the
+    optimized HLO text."""
+    widths = {c.name: c.width for c in spec.channels}
+
+    def commit(buf, t, pays, delays, masks, drop):
+        sends = [ch.Send(nm, p, dl, m)
+                 for nm, p, dl, m in zip(names, pays, delays, masks)]
+        return ch.ring_commit(spec, {"buf": buf}, t, sends, drop=drop,
+                              backend="pallas")["buf"]
+
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    sds = partial(jax.ShapeDtypeStruct, sharding=sharding)
+    args = (sds((d, n, n, spec.k), f32), sds((), i32),
+            [sds((n, n, widths[nm]), f32) for nm in names],
+            [sds((n, n), i32) for _ in names],
+            [sds((n, n), b) for _ in names], sds((n, n), b))
+    return jax.jit(commit).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("d", [256, 1024])
+def test_ring_commit_kernel_compiles_sporades_ring(one_chip, d):
+    """The Sporades ring (n = 5, K = 50) at the fig6 (256-slot) and
+    paper-ddos (1,024-slot) horizons, with one channel sent twice so the
+    kernel merges two max planes."""
+    n = 5
+    spec = sporades.ring_spec(n)
+    names = [c.name for c in spec.channels] + ["vote"]
+    hlo = _compile_commit(spec, d, n, one_chip, names)
+    assert "tpu_custom_call" in hlo
+
+
+def test_ring_commit_kernel_compiles_multipaxos_ring(one_chip):
+    """The Multi-Paxos ring, whose ``fw`` channel add-merges."""
+    n = 5
+    spec = paxos.ring_spec(n, False)
+    assert any(c.additive for c in spec.channels)
+    names = [c.name for c in spec.channels]
+    hlo = _compile_commit(spec, 256, n, one_chip, names)
+    assert "tpu_custom_call" in hlo
+
+
+def test_canonical_sporades_program_compiles(one_chip):
+    """One whole canonical mandator-sporades sweep program (one lane, the
+    256-slot canonical ring) with the Pallas commit, for one v5e chip."""
+    cfg = SMRConfig(sim_seconds=1.0, channel_backend="pallas")
+    _, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = experiment._lower(
+        cfg, SweepSpec(rates=(150_000,)))
+    assert sig.lanes == 1 and sig.horizon == 256
+    args = jax.tree.map(lambda x: _shape(x[:1], one_chip),
+                        (env_b, wl_b, rate_b, seed_b))
+    fn = jax.jit(partial(experiment._sweep_body, "mandator-sporades", cfg,
+                         mode))
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
