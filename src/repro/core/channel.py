@@ -195,14 +195,16 @@ def ring_deliver(spec: RingSpec, ring: Dict[str, jax.Array], t: jax.Array
     {name: (flags [n, n] bool, payload [n, n, P])} — identical to what the
     per-channel ``deliver`` returns for each channel. The slot is NOT
     cleared here; ``ring_commit`` clears it (sends never target slot t, so
-    the clear commutes across the tick)."""
-    slot = ring["buf"][t % ring["buf"].shape[0]]         # [n, n, K]
-    out = {}
-    for c in spec.channels:
-        off = spec.offset(c.name)
-        out[c.name] = (slot[..., spec.flag(c.name)] > 0.5,
-                       slot[..., off:off + c.width])
-    return out
+    the clear commutes across the tick). Runs under the ``ring_deliver``
+    named scope."""
+    with jax.named_scope("ring_deliver"):
+        slot = ring["buf"][t % ring["buf"].shape[0]]         # [n, n, K]
+        out = {}
+        for c in spec.channels:
+            off = spec.offset(c.name)
+            out[c.name] = (slot[..., spec.flag(c.name)] > 0.5,
+                           slot[..., off:off + c.width])
+        return out
 
 
 def ring_commit(spec: RingSpec, ring: Dict[str, jax.Array], t: jax.Array,
@@ -212,25 +214,27 @@ def ring_commit(spec: RingSpec, ring: Dict[str, jax.Array], t: jax.Array,
     merge every buffered send — one scatter-max (+ one scatter-add if the
     spec has additive channels), via repro.kernels.channel_ring. ``drop``
     is the tick's scenario link-cut mask, applied to every send (silent
-    omission), exactly as the per-channel path passed it to ``send``."""
-    dmax = ring["buf"].shape[0]
-    # the fused scatter-add sums duplicate rows in one op, which float
-    # non-associativity could tell apart from sequential per-send adds —
-    # the bitwise-equivalence contract therefore requires additive
-    # channels to send at most once per tick (max-merged channels may
-    # repeat freely: max is order-free)
-    add_names = [s.name for s in sends if spec[s.name].additive]
-    assert len(add_names) == len(set(add_names)), \
-        f"additive channel sent twice in one tick: {add_names}"
-    entries, layout = [], []
-    for s in sends:
-        c = spec[s.name]
-        mask = s.mask if drop is None else s.mask & ~drop
-        slot = (t + jnp.clip(s.delay_ticks, 1, dmax - 1)) % dmax
-        neutral = 0.0 if c.additive else NEG
-        vals = jnp.where(mask[..., None], s.payload, neutral)
-        entries.append((slot, vals, mask.astype(jnp.float32)))
-        layout.append(spec.layout(s.name))
-    buf = ring_ops.ring_commit(ring["buf"], t, jnp.asarray(spec.fill()),
-                               entries, layout, backend=backend)
-    return {"buf": buf}
+    omission), exactly as the per-channel path passed it to ``send``.
+    Runs under the ``ring_commit`` named scope, whatever the backend."""
+    with jax.named_scope("ring_commit"):
+        dmax = ring["buf"].shape[0]
+        # the fused scatter-add sums duplicate rows in one op, which float
+        # non-associativity could tell apart from sequential per-send adds —
+        # the bitwise-equivalence contract therefore requires additive
+        # channels to send at most once per tick (max-merged channels may
+        # repeat freely: max is order-free)
+        add_names = [s.name for s in sends if spec[s.name].additive]
+        assert len(add_names) == len(set(add_names)), \
+            f"additive channel sent twice in one tick: {add_names}"
+        entries, layout = [], []
+        for s in sends:
+            c = spec[s.name]
+            mask = s.mask if drop is None else s.mask & ~drop
+            slot = (t + jnp.clip(s.delay_ticks, 1, dmax - 1)) % dmax
+            neutral = 0.0 if c.additive else NEG
+            vals = jnp.where(mask[..., None], s.payload, neutral)
+            entries.append((slot, vals, mask.astype(jnp.float32)))
+            layout.append(spec.layout(s.name))
+        buf = ring_ops.ring_commit(ring["buf"], t, jnp.asarray(spec.fill()),
+                                   entries, layout, backend=backend)
+        return {"buf": buf}

@@ -48,8 +48,11 @@ protocol's program was traced — the equivalence tests
 (tests/test_experiment.py, tests/test_workloads.py) pin a whole grid to
 one trace — ``program_signatures()`` the distinct compiled signatures per
 protocol (tests/test_compile_cache.py pins figs 6/7/9 to one), and
-``timing_stats()`` the compile-vs-run wall-clock split plus the resolved
-ring horizon. ``compile_report()`` joins all of that with the
+``timing_stats()`` the compile-vs-run wall-clock split, the host spans
+of every grid (``lower``, ``enqueue``, ``readback``, ``decode``: seconds
+and counts, each also a ``jax.profiler.TraceAnnotation`` named
+``experiment.<span>`` on the device trace's clock) plus the resolved ring
+horizon. ``compile_report()`` joins all of that with the
 persistent-cache counters (``repro.core.compile_cache``), which
 benchmarks/run.py persists per suite to BENCH_core.json. Every sweep also
 ``compile_cache.ensure()``s the persistent cache, so repeat processes pay
@@ -94,6 +97,10 @@ CANONICAL_MIN_WINDOWS = 32
 
 _TRACE_COUNTS: Dict[str, int] = {}
 _TIMING: Dict[str, Dict[str, float]] = {}
+# the host spans of one grid, in order: scenario/workload tables to the
+# stacked inputs; program lookup plus the launches (with the inputs'
+# transfer to the device); the results' transfer to the host; result rows
+SPANS = ("lower", "enqueue", "readback", "decode")
 _SIGNATURES: Dict[str, set] = {}
 
 
@@ -153,16 +160,51 @@ def compile_report() -> Dict:
 
 def timing_stats() -> Dict[str, Dict[str, float]]:
     """Per-protocol wall-clock of the sweep dispatches since the last
-    reset: ``compile_s`` (dispatches that traced: trace + lower + backend
-    compile or persistent-cache load — execution is excluded because
-    dispatch is async), ``run_s`` (cache-hit dispatch overhead plus every
-    ``collect()``'s execution + readback wall), ``dispatches``, and
-    ``horizon`` (the resolved ring size of the latest sweep)."""
+    reset: ``<span>_s`` and ``<span>_n``, the seconds and count of each
+    host span in ``SPANS``; ``compile_s`` (the ``enqueue`` spans that
+    traced: trace + lower + backend compile or persistent-cache load —
+    execution is excluded because dispatch is async), ``run_s`` (the
+    other ``enqueue`` spans plus every ``readback`` span, which waits for
+    execution), ``dispatches``, and ``horizon`` (the resolved ring size
+    of the latest sweep)."""
     return {k: dict(v) for k, v in _TIMING.items()}
 
 
 def reset_timing_stats() -> None:
     _TIMING.clear()
+
+
+def _stats(protocol: str) -> Dict[str, float]:
+    st = _TIMING.get(protocol)
+    if st is None:
+        st = _TIMING[protocol] = {"compile_s": 0.0, "run_s": 0.0,
+                                  "dispatches": 0, "horizon": 0}
+        for name in SPANS:
+            st[f"{name}_s"], st[f"{name}_n"] = 0.0, 0
+    return st
+
+
+class _span:
+    """One host span of a grid: a ``TraceAnnotation`` named
+    ``experiment.<name>`` (so a profiler trace shows it on the device
+    trace's clock) whose ``perf_counter`` seconds and count add to
+    ``timing_stats()``. Off the profiler it costs a few microseconds."""
+
+    def __init__(self, protocol: str, name: str):
+        self.protocol, self.name, self.seconds = protocol, name, 0.0
+        self._ann = jax.profiler.TraceAnnotation(f"experiment.{name}")
+
+    def __enter__(self) -> "_span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        st = _stats(self.protocol)
+        st[f"{self.name}_s"] += self.seconds
+        st[f"{self.name}_n"] += 1
 
 
 @dataclass(frozen=True)
@@ -416,19 +458,25 @@ class PendingSweep:
     def collect(self) -> List[Dict]:
         if self._results is not None:
             return self._results
-        t0 = time.perf_counter()
-        chunks = [jax.tree.map(np.asarray, o) for o in self._outs]
-        # tree-aware concat: with tracing on, sim_point outputs carry
-        # nested subtrees (per-layer obs rings), not just flat arrays
-        out = (chunks[0] if len(chunks) == 1 else
-               jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *chunks))
-        if self._n_real is not None:
-            # sharded decode: drop the rows that padded the grid to a
-            # multiple of the mesh size (repeats of the last real point)
-            out = jax.tree.map(lambda x: x[:self._n_real], out)
-        stats = _TIMING[self.protocol]
-        stats["run_s"] += time.perf_counter() - t0
+        with _span(self.protocol, "readback") as sp:
+            chunks = [jax.tree.map(np.asarray, o) for o in self._outs]
+            # tree-aware concat: with tracing on, sim_point outputs carry
+            # nested subtrees (per-layer obs rings), not just flat arrays
+            out = (chunks[0] if len(chunks) == 1 else
+                   jax.tree.map(lambda *xs: np.concatenate(xs, axis=0),
+                                *chunks))
+            if self._n_real is not None:
+                # sharded decode: drop the rows that padded the grid to a
+                # multiple of the mesh size (repeats of the last real point)
+                out = jax.tree.map(lambda x: x[:self._n_real], out)
+        _stats(self.protocol)["run_s"] += sp.seconds
         self._outs = None
+        with _span(self.protocol, "decode"):
+            self._results = self._rows(out)
+        return self._results
+
+    def _rows(self, out: Dict) -> List[Dict]:
+        """One result dict per point from the host copies of the outputs."""
         results: List[Dict] = []
         for i, (rate, seed, fi, wi) in enumerate(self._pts):
             r: Dict = {"protocol": self.protocol, "rate": rate,
@@ -469,7 +517,6 @@ class PendingSweep:
             if "mon" in out:
                 r["mon"] = jax.tree.map(lambda x: x[i], out["mon"])
             results.append(r)
-        self._results = results
         return results
 
 
@@ -508,17 +555,10 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
 
     compile_cache.ensure()
     mesh = dmesh.as_grid_mesh(mesh)
-    pts, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = _lower(
-        cfg, spec, canonical=canonical)
-    # the sharded path registers the SAME canonical signature — the point
-    # computation (and so the persistent-cache key material) is unchanged;
-    # only the orchestration around it is
-    _SIGNATURES.setdefault(protocol, set()).add(sig)
-    traces_before = _TRACE_COUNTS.get(protocol, 0)
-    if mesh is not None:
-        n_dev = int(mesh.devices.size)
-        _SHARD_SIGNATURES.setdefault(protocol, set()).add((sig, n_dev))
-        pad = (-len(pts)) % n_dev
+    with _span(protocol, "lower"):
+        pts, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = _lower(
+            cfg, spec, canonical=canonical)
+        pad = (-len(pts)) % int(mesh.devices.size) if mesh is not None else 0
         if pad:
             # pad the grid to a multiple of the mesh size by repeating the
             # last real point; collect() slices the repeats back off
@@ -527,52 +567,50 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
             env_b = jax.tree.map(lambda x: x[idx], env_b)
             wl_b = jax.tree.map(lambda x: x[idx], wl_b)
             rate_b, seed_b = rate_b[idx], seed_b[idx]
-        fn = _acquire_sharded(protocol, cfg, mode, mesh)
-        t0 = time.perf_counter()
-        outs = [fn(env_b, wl_b, rate_b, seed_b)]
-        dt = time.perf_counter() - t0
-        stats = _TIMING.setdefault(protocol, {
-            "compile_s": 0.0, "run_s": 0.0, "dispatches": 0, "horizon": 0})
-        bucket = ("compile_s"
-                  if _TRACE_COUNTS.get(protocol, 0) > traces_before
-                  else "run_s")
-        stats[bucket] += dt
-        stats["dispatches"] += 1
-        stats["horizon"] = int(cfg.delay_horizon_ticks)
-        return PendingSweep(protocol, pts=pts, wl_names=wl_names, outs=outs,
-                            n_real=len(pts))
-    t0 = time.perf_counter()
-    if sig.lanes == len(pts):
-        chunks = [(env_b, wl_b, rate_b, seed_b)]
-    else:
-        # canonical: the grid runs as per-point async dispatches of the
-        # shared ``CANONICAL_LANES``-wide program (lanes are independent
-        # under vmap, so this is bitwise identical to one wide dispatch)
-        chunks = [(jax.tree.map(lambda x: x[i:i + 1], env_b),
-                   jax.tree.map(lambda x: x[i:i + 1], wl_b),
-                   rate_b[i:i + 1], seed_b[i:i + 1])
-                  for i in range(len(pts))]
-    if canonical:
-        # canonical programs additionally go through the on-disk program
-        # store: warm processes deserialize the traced computation instead
-        # of re-tracing it (the persistent XLA cache below then supplies
-        # the executable)
-        prog = _acquire_program(protocol, cfg, mode, chunks[0])
-        outs = [prog(*c) for c in chunks]
-    else:
-        outs = [_sweep_compiled(protocol, cfg, mode, *c) for c in chunks]
-    dt = time.perf_counter() - t0
-    stats = _TIMING.setdefault(protocol, {
-        "compile_s": 0.0, "run_s": 0.0, "dispatches": 0, "horizon": 0})
-    # dispatch returns before the device finishes: this bucket is pure
-    # trace + lower + (backend compile | cache load); collect() adds the
-    # execution + readback wall to run_s
-    bucket = ("compile_s" if _TRACE_COUNTS.get(protocol, 0) > traces_before
-              else "run_s")
-    stats[bucket] += dt
+    # the sharded path registers the SAME canonical signature — the point
+    # computation (and so the persistent-cache key material) is unchanged;
+    # only the orchestration around it is
+    _SIGNATURES.setdefault(protocol, set()).add(sig)
+    if mesh is not None:
+        _SHARD_SIGNATURES.setdefault(protocol, set()).add(
+            (sig, int(mesh.devices.size)))
+    traces_before = _TRACE_COUNTS.get(protocol, 0)
+    with _span(protocol, "enqueue") as sp:
+        if mesh is not None:
+            fn = _acquire_sharded(protocol, cfg, mode, mesh)
+            outs = [fn(env_b, wl_b, rate_b, seed_b)]
+        else:
+            if sig.lanes == len(pts):
+                chunks = [(env_b, wl_b, rate_b, seed_b)]
+            else:
+                # canonical: the grid runs as per-point async dispatches of
+                # the shared ``CANONICAL_LANES``-wide program (lanes are
+                # independent under vmap, so this is bitwise identical to
+                # one wide dispatch)
+                chunks = [(jax.tree.map(lambda x: x[i:i + 1], env_b),
+                           jax.tree.map(lambda x: x[i:i + 1], wl_b),
+                           rate_b[i:i + 1], seed_b[i:i + 1])
+                          for i in range(len(pts))]
+            if canonical:
+                # canonical programs additionally go through the on-disk
+                # program store: warm processes deserialize the traced
+                # computation instead of re-tracing it (the persistent XLA
+                # cache below then supplies the executable)
+                prog = _acquire_program(protocol, cfg, mode, chunks[0])
+                outs = [prog(*c) for c in chunks]
+            else:
+                outs = [_sweep_compiled(protocol, cfg, mode, *c)
+                        for c in chunks]
+    stats = _stats(protocol)
+    # dispatch returns before the device finishes: an enqueue that traced
+    # is pure trace + lower + (backend compile | cache load); collect()
+    # adds the execution + readback wall to run_s
+    traced = _TRACE_COUNTS.get(protocol, 0) > traces_before
+    stats["compile_s" if traced else "run_s"] += sp.seconds
     stats["dispatches"] += 1
     stats["horizon"] = int(cfg.delay_horizon_ticks)
-    return PendingSweep(protocol, pts=pts, wl_names=wl_names, outs=outs)
+    return PendingSweep(protocol, pts=pts, wl_names=wl_names, outs=outs,
+                        n_real=len(pts) if mesh is not None else None)
 
 
 def run_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,

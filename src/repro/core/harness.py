@@ -337,45 +337,49 @@ def sim_point(protocol: str, cfg: SMRConfig, env: Dict,
     scalar metrics keep the exact unreduced op sequence (bitwise-equal
     values), the per-batch/per-tick arrays in ``REDUCED_DROPS`` are
     omitted, and a fixed-size latency ``sketch`` is added in their place
-    so each point returns O(SKETCH_BINS) bytes of distribution."""
+    so each point returns O(SKETCH_BINS) bytes of distribution.
+
+    Everything after the scan runs under the ``extract`` named scope."""
     n_ticks = netsim.sim_ticks(cfg)
     st, trace = _scan_body(protocol, cfg, n_ticks, rate_per_tick, env, seed,
                            wlt, mode)
-    if protocol == "mandator":
-        # dissemination completion = "commit" for availability accounting
-        wl, cvc = st["m"]["wl"], trace["own_round"]
-    elif protocol in ("mandator-sporades", "mandator-paxos"):
-        # batch r commits once the committed VC reaches r (1-based rounds)
-        wl, cvc = st["m"]["wl"], trace["cvc"]
-    elif protocol == "multipaxos":
-        wl, cvc = st["p"]["wl"], trace["committed_slot"]
-    else:
-        raise ValueError(protocol)
-    commit_t = _vc_commit_ticks(cvc, wl["batch_count"].shape[1])
-    out = _batch_metrics(cfg, wl["batch_create_t"], wl["batch_arr_mean"],
-                         wl["batch_count"], commit_t)
-    if protocol == "mandator-sporades":
-        out["async_frac"] = jnp.mean(trace["is_async"].astype(jnp.float32))
-        out["views"] = jnp.max(trace["v_cur"])
-        out["cvc_all"] = trace["cvc_all"]          # [ticks, n, n]
-        out["commit_key"] = trace["commit_key"]    # [ticks, n]
-    if mode.closed:
-        out["inflight_max"] = jnp.max(trace["inflight"], axis=0)   # [n]
-    if cfg.trace_level != obs.TraceLevel.OFF:
-        out.update(_phase_breakdown(protocol, cfg, wl, trace, commit_t,
-                                    n_ticks))
-        rings = {layer: obs.public_view(st[k].get("tr"))
-                 for k, layer in (("m", "mandator"), ("s", "sporades"),
-                                  ("p", "paxos")) if k in st}
-        out["obs"] = {k: v for k, v in rings.items() if v is not None}
-    if hmon.on(cfg.monitor_level):
-        out["mon"] = hmon.public_view(st["mon"], n_ticks)
-    if reduced:
-        out = {k: v for k, v in out.items() if k not in REDUCED_DROPS}
-        out["sketch"] = _latency_sketch(
-            cfg, wl["batch_create_t"], wl["batch_arr_mean"],
-            wl["batch_count"], commit_t)
-    return out
+    # metric extraction: everything after the scan, named for the trace
+    with jax.named_scope("extract"):
+        if protocol == "mandator":
+            # dissemination completion = "commit" for availability accounting
+            wl, cvc = st["m"]["wl"], trace["own_round"]
+        elif protocol in ("mandator-sporades", "mandator-paxos"):
+            # batch r commits once the committed VC reaches r (1-based rounds)
+            wl, cvc = st["m"]["wl"], trace["cvc"]
+        elif protocol == "multipaxos":
+            wl, cvc = st["p"]["wl"], trace["committed_slot"]
+        else:
+            raise ValueError(protocol)
+        commit_t = _vc_commit_ticks(cvc, wl["batch_count"].shape[1])
+        out = _batch_metrics(cfg, wl["batch_create_t"], wl["batch_arr_mean"],
+                             wl["batch_count"], commit_t)
+        if protocol == "mandator-sporades":
+            out["async_frac"] = jnp.mean(trace["is_async"].astype(jnp.float32))
+            out["views"] = jnp.max(trace["v_cur"])
+            out["cvc_all"] = trace["cvc_all"]          # [ticks, n, n]
+            out["commit_key"] = trace["commit_key"]    # [ticks, n]
+        if mode.closed:
+            out["inflight_max"] = jnp.max(trace["inflight"], axis=0)   # [n]
+        if cfg.trace_level != obs.TraceLevel.OFF:
+            out.update(_phase_breakdown(protocol, cfg, wl, trace, commit_t,
+                                        n_ticks))
+            rings = {layer: obs.public_view(st[k].get("tr"))
+                     for k, layer in (("m", "mandator"), ("s", "sporades"),
+                                      ("p", "paxos")) if k in st}
+            out["obs"] = {k: v for k, v in rings.items() if v is not None}
+        if hmon.on(cfg.monitor_level):
+            out["mon"] = hmon.public_view(st["mon"], n_ticks)
+        if reduced:
+            out = {k: v for k, v in out.items() if k not in REDUCED_DROPS}
+            out["sketch"] = _latency_sketch(
+                cfg, wl["batch_create_t"], wl["batch_arr_mean"],
+                wl["batch_count"], commit_t)
+        return out
 
 
 def _phase_breakdown(protocol: str, cfg: SMRConfig, wl: Dict, trace: Dict,

@@ -64,6 +64,15 @@ def init_state(cfg: SMRConfig, n_ticks: int, closed: bool = False) -> Dict:
 def tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
          rate_per_tick: jax.Array, wlt: Dict | None = None,
          mode: workload.WorkloadMode = workload.TRIVIAL_MODE) -> Dict:
+    """One simulator tick of Mandator dissemination, under the
+    ``mandator`` named scope (the device trace's per-layer time)."""
+    with jax.named_scope("mandator"):
+        return _tick(st, t, key, env, cfg, rate_per_tick, wlt, mode)
+
+
+def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
+          rate_per_tick: jax.Array, wlt: Dict | None,
+          mode: workload.WorkloadMode) -> Dict:
     n = cfg.n_replicas
     f = (n - 1) // 2
     quorum = n - f

@@ -85,6 +85,17 @@ def tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
          rate_per_tick: jax.Array, mandator_mode: bool,
          lcr: jax.Array | None = None, wlt: Dict | None = None,
          mode: workload.WorkloadMode = workload.TRIVIAL_MODE) -> Dict:
+    """One simulator tick of (Mandator-)Paxos, under the ``paxos`` named
+    scope (the device trace's per-layer time)."""
+    with jax.named_scope("paxos"):
+        return _tick(st, t, key, env, cfg, rate_per_tick, mandator_mode,
+                     lcr, wlt, mode)
+
+
+def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
+          rate_per_tick: jax.Array, mandator_mode: bool,
+          lcr: jax.Array | None, wlt: Dict | None,
+          mode: workload.WorkloadMode) -> Dict:
     n = cfg.n_replicas
     maj = n // 2 + 1
     alive = netsim.alive(env, t)

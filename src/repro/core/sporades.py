@@ -97,8 +97,15 @@ def _leader_of(v, n):
 
 def tick(st: Dict, t: jax.Array, env: Dict, cfg: SMRConfig,
          lcr: jax.Array) -> Dict:
-    """One simulator tick. lcr: Mandator getClientRequests() per replica
+    """One simulator tick, under the ``sporades`` named scope (the device
+    trace's per-layer time). lcr: Mandator getClientRequests() per replica
     [n, n] (row i = replica i's vector clock)."""
+    with jax.named_scope("sporades"):
+        return _tick(st, t, env, cfg, lcr)
+
+
+def _tick(st: Dict, t: jax.Array, env: Dict, cfg: SMRConfig,
+          lcr: jax.Array) -> Dict:
     n = cfg.n_replicas
     f = (n - 1) // 2
     q = n - f

@@ -58,29 +58,31 @@ def arrive(wl: Dict, key: jax.Array, t: jax.Array, rate_per_tick: jax.Array,
            alive: jax.Array, wlt: Optional[Dict] = None,
            mode: WorkloadMode = TRIVIAL_MODE) -> Dict:
     """Poisson arrivals this tick at each origin's clients. ``wlt`` is the
-    compiled workload table (required unless mode.trivial)."""
-    wl = dict(wl)
-    if mode.trivial:
-        lam = jnp.broadcast_to(rate_per_tick, alive.shape)
-        cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
-    else:
-        mult = wlt["rate_of"][wlt["win_of_tick"][t]]           # [n]
-        lam = rate_per_tick * mult
-        if mode.closed:
-            # pool size via Little's law at the sweep rate; submission is
-            # gated on requests still in flight and capped at `cap`
-            inflight = wl["cl_submitted"] - wl["cl_done"]
-            clients = rate_per_tick * wlt["think_ticks"] * mult
-            lam_cl = jnp.clip(clients - inflight, 0.0) / wlt["think_ticks"]
-            lam = jnp.where(wlt["closed"] > 0, lam_cl, lam)
-        cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
-        if mode.closed:
-            room = jnp.clip(wlt["cap"] - inflight, 0.0)
-            cnt = jnp.where(wlt["closed"] > 0, jnp.minimum(cnt, room), cnt)
-            wl["cl_submitted"] = wl["cl_submitted"] + cnt
-    wl["buffer"] = wl["buffer"] + cnt
-    wl["buffer_tsum"] = wl["buffer_tsum"] + cnt * t
-    return wl
+    compiled workload table (required unless mode.trivial). Runs under the
+    ``arrivals`` named scope, the layer the device trace reads."""
+    with jax.named_scope("arrivals"):
+        wl = dict(wl)
+        if mode.trivial:
+            lam = jnp.broadcast_to(rate_per_tick, alive.shape)
+            cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
+        else:
+            mult = wlt["rate_of"][wlt["win_of_tick"][t]]           # [n]
+            lam = rate_per_tick * mult
+            if mode.closed:
+                # pool size via Little's law at the sweep rate; submission is
+                # gated on requests still in flight and capped at `cap`
+                inflight = wl["cl_submitted"] - wl["cl_done"]
+                clients = rate_per_tick * wlt["think_ticks"] * mult
+                lam_cl = jnp.clip(clients - inflight, 0.0) / wlt["think_ticks"]
+                lam = jnp.where(wlt["closed"] > 0, lam_cl, lam)
+            cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
+            if mode.closed:
+                room = jnp.clip(wlt["cap"] - inflight, 0.0)
+                cnt = jnp.where(wlt["closed"] > 0, jnp.minimum(cnt, room), cnt)
+                wl["cl_submitted"] = wl["cl_submitted"] + cnt
+        wl["buffer"] = wl["buffer"] + cnt
+        wl["buffer_tsum"] = wl["buffer_tsum"] + cnt * t
+        return wl
 
 
 def refill_cpu(wl: Dict, cpu_req_per_tick: jax.Array) -> Dict:

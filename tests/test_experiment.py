@@ -74,3 +74,38 @@ def test_analytic_baselines_share_the_sweep_api():
 def test_unknown_protocol_rejected():
     with pytest.raises(ValueError):
         run_sweep("zab", CFG, SweepSpec(rates=(1_000,)))
+
+
+def test_host_spans_count_once_per_grid_and_feed_compile_and_run():
+    """One grid enters each host span once, and ``compile_s``/``run_s``
+    are made of the same span timings: the enqueue (in one bucket or the
+    other, by whether it traced) and the readback."""
+    experiment.reset_timing_stats()
+    run_sweep("multipaxos", CFG, SweepSpec(rates=(20_000, 40_000)))
+    st = experiment.timing_stats()["multipaxos"]
+    assert st["dispatches"] == 1
+    for name in experiment.SPANS:
+        assert st[f"{name}_n"] == 1, name
+        assert st[f"{name}_s"] > 0.0, name
+    assert st["compile_s"] + st["run_s"] == pytest.approx(
+        st["enqueue_s"] + st["readback_s"], rel=1e-12, abs=0.0)
+    assert st["compile_s"] in (0.0, st["enqueue_s"])
+
+
+def test_host_spans_are_annotations_on_the_profiler_clock(tmp_path):
+    """The spans reach a profiler trace as ``experiment.<span>`` host
+    events, nested in the order a grid runs them."""
+    import jax
+    from jax.profiler import ProfileData
+    spec = SweepSpec(rates=(20_000,))
+    run_sweep("multipaxos", CFG, spec)
+    with jax.profiler.trace(str(tmp_path)):
+        run_sweep("multipaxos", CFG, spec)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = sorted((e.start_ns, e.name)
+                    for p in ProfileData.from_file(str(path)).planes
+                    if p.name.startswith("/host:")
+                    for ln in p.lines for e in ln.events
+                    if e.name.startswith("experiment."))
+    assert [n for _, n in events] == [f"experiment.{s}"
+                                      for s in experiment.SPANS]

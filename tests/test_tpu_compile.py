@@ -10,7 +10,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
 imports this file.
 """
+import contextlib
 import os
+import re
 from functools import partial
 
 import jax
@@ -91,18 +93,73 @@ def test_ring_commit_kernel_compiles_multipaxos_ring(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def test_canonical_sporades_program_compiles(one_chip):
+def _compile_canonical_ms(sharding):
     """One whole canonical mandator-sporades sweep program (one lane, the
-    256-slot canonical ring) with the Pallas commit, for one v5e chip."""
+    256-slot canonical ring) with the Pallas commit, compiled for
+    ``sharding``'s device."""
     cfg = SMRConfig(sim_seconds=1.0, channel_backend="pallas")
     _, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = experiment._lower(
         cfg, SweepSpec(rates=(150_000,)))
     assert sig.lanes == 1 and sig.horizon == 256
-    args = jax.tree.map(lambda x: _shape(x[:1], one_chip),
+    args = jax.tree.map(lambda x: _shape(x[:1], sharding),
                         (env_b, wl_b, rate_b, seed_b))
     fn = jax.jit(partial(experiment._sweep_body, "mandator-sporades", cfg,
                          mode))
-    compiled = fn.lower(*args).compile()
+    return fn.lower(*args).compile()
+
+
+@pytest.fixture(scope="module")
+def canonical_ms(one_chip):
+    return _compile_canonical_ms(one_chip)
+
+
+def test_canonical_sporades_program_compiles(canonical_ms):
+    """The canonical mandator-sporades program compiles for one v5e chip
+    with the Pallas commit in it."""
+    compiled = canonical_ms
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+LAYER_SCOPES = ("arrivals", "ring_deliver", "ring_commit", "mandator",
+                "sporades", "extract")
+
+
+def _op_names(hlo: str):
+    return re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def test_named_scopes_reach_the_chip_program(canonical_ms):
+    """Every layer's named scope is in the compiled program's ``op_name``
+    metadata, and the Pallas commit lies under ``ring_commit``."""
+    hlo = canonical_ms.as_text()
+    comps = {c for name in _op_names(hlo) for c in name.split("/")}
+    for scope in LAYER_SCOPES:
+        assert scope in comps or f"vmap({scope})" in comps, scope
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    for ln in kernels:
+        (name,) = _op_names(ln)
+        assert "/ring_commit/" in name, name
+
+
+def test_named_scopes_add_no_instruction(canonical_ms, one_chip,
+                                         monkeypatch):
+    """The scopes are metadata only: with ``jax.named_scope`` a null
+    context the chip's compiler emits the same instructions, in the same
+    order, on the same shapes. Only the numbers in instruction names may
+    differ (the lowering numbers ops as it emits them)."""
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compile_canonical_ms(one_chip).as_text()
+    assert "/ring_commit/" not in bare
+
+    def instructions(hlo):
+        return [re.sub(r"%[\w.\-]+", "%",
+                       re.sub(r",? metadata=\{[^}]*\}", "", ln))
+                for ln in hlo.splitlines() if " = " in ln]
+    scoped = instructions(canonical_ms.as_text())
+    assert len(scoped) > 1000
+    assert scoped == instructions(bare)
