@@ -165,8 +165,9 @@ def timing_stats() -> Dict[str, Dict[str, float]]:
     traced: trace + lower + backend compile or persistent-cache load —
     execution is excluded because dispatch is async), ``run_s`` (the
     other ``enqueue`` spans plus every ``readback`` span, which waits for
-    execution), ``dispatches``, and ``horizon`` (the resolved ring size
-    of the latest sweep)."""
+    execution), ``dispatches``, ``horizon`` (the resolved ring size
+    of the latest sweep), and ``arrivals_hoisted`` / ``arrivals_in_scan``
+    (the programs built, by where they draw the arrivals)."""
     return {k: dict(v) for k, v in _TIMING.items()}
 
 
@@ -178,7 +179,9 @@ def _stats(protocol: str) -> Dict[str, float]:
     st = _TIMING.get(protocol)
     if st is None:
         st = _TIMING[protocol] = {"compile_s": 0.0, "run_s": 0.0,
-                                  "dispatches": 0, "horizon": 0}
+                                  "dispatches": 0, "horizon": 0,
+                                  "arrivals_hoisted": 0,
+                                  "arrivals_in_scan": 0}
         for name in SPANS:
             st[f"{name}_s"], st[f"{name}_n"] = 0.0, 0
     return st
@@ -233,11 +236,22 @@ class SweepSpec:
                 * len(self.workloads))
 
 
+def _count_build(protocol: str, mode: wlc.WorkloadMode) -> None:
+    """Count one program build (a trace, or a load from the program
+    store) in ``trace_counts()``, and in ``timing_stats()`` the arrival
+    path the program takes: ``arrivals_hoisted`` (drawn before the tick
+    scan) or ``arrivals_in_scan`` (drawn tick by tick, closed loop)."""
+    _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
+    path = ("arrivals_hoisted" if harness.hoists_arrivals(mode)
+            else "arrivals_in_scan")
+    _stats(protocol)[path] += 1
+
+
 def _sweep_body(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
                 env_b: Dict, wl_b: Dict, rate_b: jax.Array,
                 seed_b: jax.Array) -> Dict:
     # body executes only while tracing, so this counts program builds
-    _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
+    _count_build(protocol, mode)
     return jax.vmap(lambda env, wlt, rate, seed: harness.sim_point(
         protocol, cfg, env, rate, seed, wlt, mode))(
         env_b, wl_b, rate_b, seed_b)
@@ -289,7 +303,7 @@ def _acquire_program(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
         # a loaded program counts as materialized, exactly like a fresh
         # trace would — per-process accounting stays identical whether
         # the store was warm or cold
-        _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
+        _count_build(protocol, mode)
     else:
         f = jax.jit(partial(_sweep_body, protocol, cfg, mode))
         # traces once (the body counts it)
@@ -333,7 +347,7 @@ def _acquire_sharded(protocol: str, cfg: SMRConfig, mode: wlc.WorkloadMode,
     from jax.sharding import PartitionSpec
 
     def body(env_b, wl_b, rate_b, seed_b):
-        _TRACE_COUNTS[protocol] = _TRACE_COUNTS.get(protocol, 0) + 1
+        _count_build(protocol, mode)
 
         def one(point):
             env, wlt, rate, seed = point

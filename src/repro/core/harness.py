@@ -25,7 +25,7 @@ import numpy as np
 
 from repro.configs.smr import SMRConfig
 from repro.core import channel as ch
-from repro.core import mandator, netsim, paxos, sporades
+from repro.core import mandator, netsim, paxos, sporades, workload
 from repro.distributed import sketch as dsketch
 from repro.obs import monitor as hmon
 from repro.obs import trace as obs
@@ -147,6 +147,13 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
     return views
 
 
+def hoists_arrivals(mode: WorkloadMode) -> bool:
+    """Whether a program of this workload mode draws its arrivals before
+    the tick scan (open loop) rather than tick by tick inside it (closed
+    loop, whose mean depends on the requests in flight)."""
+    return not mode.closed
+
+
 def _scan_body(protocol: str, cfg: SMRConfig, n_ticks: int,
                rate_per_tick: jax.Array, env: Dict, seed: jax.Array,
                wlt: Dict | None = None,
@@ -173,13 +180,21 @@ def _scan_body(protocol: str, cfg: SMRConfig, n_ticks: int,
                                       _monitor_views(protocol, cfg, st))
         grace = hmon.stall_grace_ticks(cfg, env)
     base_key = jax.random.PRNGKey(seed)
+    ticks = jnp.arange(n_ticks, dtype=jnp.int32)
+    # open-loop arrivals depend on nothing in the carry: draw the point's
+    # whole table before the scan, one row a tick (workload.py)
+    hoisted = hoists_arrivals(mode)
+    rows = (workload.draw_arrivals(base_key, n_ticks, rate_per_tick,
+                                   cfg.n_replicas, wlt, mode)
+            if hoisted else None)
 
-    def step(carry, t):
-        key = jax.random.fold_in(base_key, t)
+    def step(carry, xs):
+        t, row = xs
+        draw = row if hoisted else jax.random.fold_in(base_key, t)
         out = {}
         if uses_mandator:
             carry = dict(carry)
-            carry["m"] = mandator.tick(carry["m"], t, key, env, cfg,
+            carry["m"] = mandator.tick(carry["m"], t, draw, env, cfg,
                                        rate_per_tick, wlt, mode)
             lcr = mandator.get_client_requests(carry["m"])
             out["own_round"] = carry["m"]["own_round"]
@@ -191,7 +206,7 @@ def _scan_body(protocol: str, cfg: SMRConfig, n_ticks: int,
             out["is_async"] = carry["s"]["is_async"]
             out["v_cur"] = carry["s"]["v_cur"]
         elif protocol == "mandator-paxos":
-            carry["p"] = paxos.tick(carry["p"], t, key, env, cfg,
+            carry["p"] = paxos.tick(carry["p"], t, None, env, cfg,
                                     rate_per_tick, True, lcr=lcr)
             out["cvc"] = jnp.max(carry["p"]["cvc"], axis=0)
             if cfg.trace_level != obs.TraceLevel.OFF:
@@ -201,7 +216,7 @@ def _scan_body(protocol: str, cfg: SMRConfig, n_ticks: int,
                 out["cvc_own"] = jnp.diagonal(carry["p"]["cvc"])
         elif protocol == "multipaxos":
             carry = dict(carry)
-            carry["p"] = paxos.tick(carry["p"], t, key, env, cfg,
+            carry["p"] = paxos.tick(carry["p"], t, draw, env, cfg,
                                     rate_per_tick, False, wlt=wlt, mode=mode)
             out["committed_slot"] = carry["p"]["committed_slot"]
         if mode.closed:
@@ -218,7 +233,7 @@ def _scan_body(protocol: str, cfg: SMRConfig, n_ticks: int,
                 check_cap=mode.closed and protocol != "multipaxos")
         return carry, out
 
-    st, trace = jax.lax.scan(step, st, jnp.arange(n_ticks, dtype=jnp.int32))
+    st, trace = jax.lax.scan(step, st, (ticks, rows))
     return st, trace
 
 

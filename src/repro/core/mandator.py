@@ -61,16 +61,17 @@ def init_state(cfg: SMRConfig, n_ticks: int, closed: bool = False) -> Dict:
     }
 
 
-def tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
+def tick(st: Dict, t: jax.Array, draw: jax.Array, env: Dict, cfg: SMRConfig,
          rate_per_tick: jax.Array, wlt: Dict | None = None,
          mode: workload.WorkloadMode = workload.TRIVIAL_MODE) -> Dict:
     """One simulator tick of Mandator dissemination, under the
-    ``mandator`` named scope (the device trace's per-layer time)."""
+    ``mandator`` named scope (the device trace's per-layer time).
+    ``draw`` is the tick's arrivals as ``workload.arrive`` takes them."""
     with jax.named_scope("mandator"):
-        return _tick(st, t, key, env, cfg, rate_per_tick, wlt, mode)
+        return _tick(st, t, draw, env, cfg, rate_per_tick, wlt, mode)
 
 
-def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
+def _tick(st: Dict, t: jax.Array, draw: jax.Array, env: Dict, cfg: SMRConfig,
           rate_per_tick: jax.Array, wlt: Dict | None,
           mode: workload.WorkloadMode) -> Dict:
     n = cfg.n_replicas
@@ -88,7 +89,7 @@ def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
     sends = []
 
     # 1) client arrivals + cpu refill
-    wl = workload.arrive(st["wl"], key, t, rate_per_tick, alive, wlt, mode)
+    wl = workload.arrive(st["wl"], draw, t, rate_per_tick, alive, wlt, mode)
     wl = workload.refill_cpu(wl, env["cpu_req_per_tick"])
 
     # 2) deliver <new-Mandator-batch>: update seen rounds + lcr, send votes

@@ -81,19 +81,21 @@ def init_state(cfg: SMRConfig, n_ticks: int, mandator_mode: bool,
     }
 
 
-def tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
-         rate_per_tick: jax.Array, mandator_mode: bool,
+def tick(st: Dict, t: jax.Array, draw: jax.Array | None, env: Dict,
+         cfg: SMRConfig, rate_per_tick: jax.Array, mandator_mode: bool,
          lcr: jax.Array | None = None, wlt: Dict | None = None,
          mode: workload.WorkloadMode = workload.TRIVIAL_MODE) -> Dict:
     """One simulator tick of (Mandator-)Paxos, under the ``paxos`` named
-    scope (the device trace's per-layer time)."""
+    scope (the device trace's per-layer time). ``draw`` is the tick's
+    arrivals as ``workload.arrive`` takes them; None in mandator mode,
+    where Mandator takes the arrivals."""
     with jax.named_scope("paxos"):
-        return _tick(st, t, key, env, cfg, rate_per_tick, mandator_mode,
+        return _tick(st, t, draw, env, cfg, rate_per_tick, mandator_mode,
                      lcr, wlt, mode)
 
 
-def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
-          rate_per_tick: jax.Array, mandator_mode: bool,
+def _tick(st: Dict, t: jax.Array, draw: jax.Array | None, env: Dict,
+          cfg: SMRConfig, rate_per_tick: jax.Array, mandator_mode: bool,
           lcr: jax.Array | None, wlt: Dict | None,
           mode: workload.WorkloadMode) -> Dict:
     n = cfg.n_replicas
@@ -120,7 +122,7 @@ def _tick(st: Dict, t: jax.Array, key: jax.Array, env: Dict, cfg: SMRConfig,
 
     # ---- request forwarding (plain mode) ----------------------------------
     if not mandator_mode:
-        wl = workload.arrive(wl, key, t, rate_per_tick, alive, wlt, mode)
+        wl = workload.arrive(wl, draw, t, rate_per_tick, alive, wlt, mode)
         # forward whole local buffer to my current leader
         cnt = wl["buffer"]
         tsum = wl["buffer_tsum"]

@@ -1,16 +1,23 @@
 """Client arrivals (open- or closed-loop) + batch bookkeeping.
 
-Arrivals are Poisson per tick per origin. The mean comes from one of two
+Arrivals are Poisson per tick per origin, keyed by
+``fold_in(PRNGKey(seed), t)``. The mean comes from one of two
 statically-selected paths (``repro.workloads.WorkloadMode``):
 
   trivial — the seed-era §5.2 baseline: ``rate_per_tick`` broadcast to all
-            origins, instruction-identical to the original scalar path
-            (what keeps the fig 6-9 artifacts byte-identical);
+            origins (what keeps the fig 6-9 artifacts byte-identical);
   table   — ``rate_per_tick x rate_of[win_of_tick[t]]`` from a compiled
             ``repro.workloads`` rate table; in closed mode the table
             instead sizes geo-placed client pools (Little's law) whose
             submission rate is gated on in-flight requests and capped at
             ``cap`` outstanding per origin.
+
+In the open modes the mean depends on nothing in the scan carry, so a
+point's whole ``[n_ticks, n]`` table of draws is made once, before the
+tick scan (``draw_arrivals``), and each tick reads its row. In closed mode
+the mean depends on the requests in flight, so each tick draws its own
+counts inside the scan. Both paths call the same sampler on the same keys
+and means, so the draws are bitwise equal.
 
 Batch records are global arrays indexed [origin, round]:
   create_t   — tick when the batch was formed
@@ -54,32 +61,57 @@ def init_workload(cfg: SMRConfig, n_ticks: int,
     return wl
 
 
-def arrive(wl: Dict, key: jax.Array, t: jax.Array, rate_per_tick: jax.Array,
+def _open_mean(rate_per_tick: jax.Array, t: jax.Array, n: int,
+               wlt: Optional[Dict], mode: WorkloadMode) -> jax.Array:
+    """The open-loop Poisson mean at tick ``t`` ([n]) or at each tick of a
+    vector ``t`` ([len(t), n]). The one expression both arrival paths use,
+    so the hoisted and the in-scan draws cannot drift apart."""
+    if mode.trivial:
+        return jnp.broadcast_to(rate_per_tick, jnp.shape(t) + (n,))
+    return rate_per_tick * wlt["rate_of"][wlt["win_of_tick"][t]]
+
+
+def draw_arrivals(seed_key: jax.Array, n_ticks: int, rate_per_tick: jax.Array,
+                  n: int, wlt: Optional[Dict] = None,
+                  mode: WorkloadMode = TRIVIAL_MODE) -> jax.Array:
+    """A point's raw open-loop Poisson counts, ``[n_ticks, n]`` float32:
+    row t is ``poisson(fold_in(seed_key, t), mean_t)``, bitwise what a
+    per-tick draw inside the scan gives. One sampler call for the whole
+    point, under the ``arrivals`` named scope. Open modes only: a
+    closed-loop mean depends on the scan carry."""
+    with jax.named_scope("arrivals"):
+        ticks = jnp.arange(n_ticks, dtype=jnp.int32)
+        keys = jax.vmap(lambda t: jax.random.fold_in(seed_key, t))(ticks)
+        lam = _open_mean(rate_per_tick, ticks, n, wlt, mode)
+        return jax.vmap(jax.random.poisson)(keys, lam).astype(jnp.float32)
+
+
+def arrive(wl: Dict, draw: jax.Array, t: jax.Array, rate_per_tick: jax.Array,
            alive: jax.Array, wlt: Optional[Dict] = None,
            mode: WorkloadMode = TRIVIAL_MODE) -> Dict:
-    """Poisson arrivals this tick at each origin's clients. ``wlt`` is the
-    compiled workload table (required unless mode.trivial). Runs under the
-    ``arrivals`` named scope, the layer the device trace reads."""
+    """This tick's arrivals at each origin's clients. In the open modes
+    ``draw`` is the tick's row of ``draw_arrivals``; in closed mode it is
+    the tick's PRNG key, and the counts are drawn here from the gated
+    mean. ``wlt`` is the compiled workload table (required unless
+    mode.trivial). Runs under the ``arrivals`` named scope, the layer the
+    device trace reads."""
     with jax.named_scope("arrivals"):
         wl = dict(wl)
-        if mode.trivial:
-            lam = jnp.broadcast_to(rate_per_tick, alive.shape)
-            cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
+        if not mode.closed:
+            cnt = draw * alive
         else:
+            # pool size via Little's law at the sweep rate; submission is
+            # gated on requests still in flight and capped at `cap`
             mult = wlt["rate_of"][wlt["win_of_tick"][t]]           # [n]
-            lam = rate_per_tick * mult
-            if mode.closed:
-                # pool size via Little's law at the sweep rate; submission is
-                # gated on requests still in flight and capped at `cap`
-                inflight = wl["cl_submitted"] - wl["cl_done"]
-                clients = rate_per_tick * wlt["think_ticks"] * mult
-                lam_cl = jnp.clip(clients - inflight, 0.0) / wlt["think_ticks"]
-                lam = jnp.where(wlt["closed"] > 0, lam_cl, lam)
-            cnt = jax.random.poisson(key, lam).astype(jnp.float32) * alive
-            if mode.closed:
-                room = jnp.clip(wlt["cap"] - inflight, 0.0)
-                cnt = jnp.where(wlt["closed"] > 0, jnp.minimum(cnt, room), cnt)
-                wl["cl_submitted"] = wl["cl_submitted"] + cnt
+            lam = _open_mean(rate_per_tick, t, alive.shape[0], wlt, mode)
+            inflight = wl["cl_submitted"] - wl["cl_done"]
+            clients = rate_per_tick * wlt["think_ticks"] * mult
+            lam_cl = jnp.clip(clients - inflight, 0.0) / wlt["think_ticks"]
+            lam = jnp.where(wlt["closed"] > 0, lam_cl, lam)
+            cnt = jax.random.poisson(draw, lam).astype(jnp.float32) * alive
+            room = jnp.clip(wlt["cap"] - inflight, 0.0)
+            cnt = jnp.where(wlt["closed"] > 0, jnp.minimum(cnt, room), cnt)
+            wl["cl_submitted"] = wl["cl_submitted"] + cnt
         wl["buffer"] = wl["buffer"] + cnt
         wl["buffer_tsum"] = wl["buffer_tsum"] + cnt * t
         return wl

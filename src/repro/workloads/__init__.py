@@ -9,9 +9,9 @@ experiment engine (``experiment.SweepSpec.workloads``) as a third sweep
 axis of ONE compiled program per protocol.
 
 The bare ``PoissonOpen()`` workload compiles to the all-ones table and a
-static fast path that is instruction-identical to the seed-era scalar
-rate, keeping the fig 6-9 artifacts byte-identical (pinned by
-tests/test_workloads.py).
+static fast path that broadcasts the scalar rate, whose draws are the
+seed-era draws bit for bit, keeping the fig 6-9 artifacts byte-identical
+(pinned by tests/test_workloads.py).
 """
 from repro.workloads.compile import (
     TRIVIAL_MODE,

@@ -21,8 +21,9 @@ lets heterogeneous workloads stack leaf-wise and vmap through
 
 ``is_trivial`` detects the all-ones open-loop table (a bare
 ``PoissonOpen()``): trivial grids take a static fast path in
-``workload.arrive`` that is instruction-identical to the seed-era scalar
-broadcast, which is what keeps the fig 6-9 artifacts byte-identical.
+``core/workload.py`` that broadcasts the scalar rate, so their draws are
+the seed-era draws bit for bit, which is what keeps the fig 6-9
+artifacts byte-identical.
 """
 from __future__ import annotations
 

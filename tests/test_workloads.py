@@ -7,11 +7,14 @@ compiled program, and the analytic baselines consuming the same compiled
 tables."""
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 from repro.configs.smr import SMRConfig
-from repro.core import experiment
+from repro.core import experiment, harness, netsim, workload
 from repro.core.experiment import SweepSpec, run_sweep
 from repro.core.harness import run_sim
 from repro.scenarios import library as scenario_library
@@ -147,6 +150,102 @@ def test_trivial_and_uniform_table_paths_agree_bitwise():
         _assert_point_equal(a, b)
         np.testing.assert_array_equal(a["origin_timeline"],
                                       b["origin_timeline"])
+
+
+ONOFF = Workload("onoff", (OnOffBurst(period_s=0.25, duty=0.5,
+                                       on_scale=2.5, off_scale=0.0),))
+CLOSED = Workload("closed", (ClosedLoop(think_ms=50.0, cap=4000.0),))
+
+
+def _draws_in_scan(seed, lam_t):
+    """The per-tick draw as a scan step makes it: one sampler call per
+    tick on ``fold_in(PRNGKey(seed), t)``."""
+    base = jax.random.PRNGKey(seed)
+
+    def step(carry, xs):
+        t, lam = xs
+        cnt = jax.random.poisson(jax.random.fold_in(base, t), lam)
+        return carry, cnt.astype(jnp.float32)
+
+    ticks = jnp.arange(lam_t.shape[0], dtype=jnp.int32)
+    return jax.lax.scan(step, None, (ticks, lam_t))[1]
+
+
+@pytest.mark.parametrize("lam,wl", [(lam, None) for lam in
+                                    (0.0, 2.0, 6.0, 10.0, 60.0, 90.0, 225.0)]
+                         + [(6.0, ONOFF)])
+def test_hoisted_arrivals_equal_per_tick_draws_bitwise(lam, wl):
+    """The pin behind the byte-identical fig 6-9 artifacts: the point's
+    arrival table drawn before the scan equals, bit for bit, the per-tick
+    draw inside a scan, across the sampler's zero, Knuth (λ < 10) and
+    rejection (λ ≥ 10) branches, and with a windowed rate table."""
+    cfg = SMRConfig(sim_seconds=1.0)
+    n_ticks = netsim.sim_ticks(cfg)
+    if wl is None:
+        mode, wlt = workload.TRIVIAL_MODE, None
+        lam_t = np.full((n_ticks, N), lam, np.float32)
+    else:
+        tab = lower(cfg, wl)
+        mode = mode_of([tab])
+        assert not mode.trivial and not mode.closed
+        wlt = {k: jnp.asarray(tab[k]) for k in ("rate_of", "win_of_tick")}
+        lam_t = np.float32(lam) * tab["rate_of"][tab["win_of_tick"]]
+        assert set(np.unique(lam_t)) == {0.0, np.float32(lam * 2.5)}
+    hoisted = jax.jit(lambda seed: workload.draw_arrivals(
+        jax.random.PRNGKey(seed), n_ticks, jnp.float32(lam), N, wlt, mode))
+    in_scan = jax.jit(_draws_in_scan)
+    for seed in (0, 7, 2**31 - 1):
+        a = np.asarray(hoisted(seed))
+        assert a.shape == (n_ticks, N) and a.dtype == np.float32
+        np.testing.assert_array_equal(a, np.asarray(in_scan(seed, lam_t)))
+    assert (a.sum() > 0) == (lam > 0)
+
+
+def _count_eqns(jaxpr, name):
+    """Equations of primitive ``name`` in a jaxpr, nested ones included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    n += _count_eqns(sub.jaxpr, name)
+                elif isinstance(sub, jcore.Jaxpr):
+                    n += _count_eqns(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("protocol", ["mandator-sporades", "multipaxos"])
+@pytest.mark.parametrize("wl,in_scan", [(None, 0), (ONOFF, 0),
+                                        (CLOSED, 2)])
+def test_open_loop_sampler_sits_before_the_tick_scan(protocol, wl, in_scan):
+    """The Poisson sampler's two data-dependent ``while`` loops (Knuth's
+    and the rejection sampler's) run once before the scan in the open
+    modes, and inside the scan's body, once a tick, in closed mode."""
+    cfg = SMRConfig(sim_seconds=0.2)
+    _, cfg, mode, env_b, wl_b, rate_b, seed_b, _ = experiment._lower(
+        cfg, SweepSpec(rates=(20_000,), workloads=(wl,)), canonical=False)
+    assert mode.closed == (in_scan > 0)
+    jaxpr = jax.make_jaxpr(lambda env, wlt, rate, seed: harness._scan_body(
+        protocol, cfg, netsim.sim_ticks(cfg), rate, env, seed, wlt, mode))(
+        *jax.tree.map(lambda x: x[0], (env_b, wl_b, rate_b, seed_b))).jaxpr
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert _count_eqns(jaxpr, "while") == 2
+    assert _count_eqns(scan.params["jaxpr"].jaxpr, "while") == in_scan
+
+
+def test_programs_count_their_arrival_path():
+    """``timing_stats()`` counts each program built by where it draws the
+    arrivals: before the scan (open loop) or in it (closed loop)."""
+    cfg = SMRConfig(sim_seconds=0.3)
+    experiment.reset_timing_stats()
+    run_sweep("multipaxos", cfg, SweepSpec(rates=(20_000,)))
+    st = experiment.timing_stats()["multipaxos"]
+    assert (st["arrivals_hoisted"], st["arrivals_in_scan"]) == (1, 0)
+    run_sweep("multipaxos", cfg, SweepSpec(
+        rates=(20_000,), workloads=(CLOSED,)))
+    st = experiment.timing_stats()["multipaxos"]
+    assert (st["arrivals_hoisted"], st["arrivals_in_scan"]) == (1, 1)
 
 
 def test_closed_loop_inflight_never_exceeds_cap():
