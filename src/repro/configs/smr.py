@@ -104,6 +104,12 @@ class SMRConfig:
     def delays_ms(self) -> np.ndarray:
         return one_way_delay_ms(self.n_replicas)
 
+    @property
+    def quorum(self) -> int:
+        """n - f replicas, with f = (n - 1) // 2 faults tolerated: the
+        votes that complete a Mandator round or a Sporades decision."""
+        return self.n_replicas - (self.n_replicas - 1) // 2
+
 
 PAPER_CLAIMS = {
     # headline numbers from the paper, used by EXPERIMENTS.md comparisons

@@ -166,9 +166,12 @@ def timing_stats() -> Dict[str, Dict[str, float]]:
     execution is excluded because dispatch is async), ``run_s`` (the
     other ``enqueue`` spans plus every ``readback`` span, which waits for
     execution), ``dispatches``, ``horizon`` (the resolved ring size
-    of the latest sweep), and ``arrivals_hoisted`` / ``arrivals_in_scan``
-    (the programs built, by where they draw the arrivals)."""
-    return {k: dict(v) for k, v in _TIMING.items()}
+    of the latest sweep), ``by_shape`` (the dispatches by program shape,
+    keyed ``n{replicas}.d{horizon}``: one process may run several), and
+    ``arrivals_hoisted`` / ``arrivals_in_scan`` (the programs built, by
+    where they draw the arrivals)."""
+    return {k: {**v, "by_shape": dict(v["by_shape"])}
+            for k, v in _TIMING.items()}
 
 
 def reset_timing_stats() -> None:
@@ -180,7 +183,7 @@ def _stats(protocol: str) -> Dict[str, float]:
     if st is None:
         st = _TIMING[protocol] = {"compile_s": 0.0, "run_s": 0.0,
                                   "dispatches": 0, "horizon": 0,
-                                  "arrivals_hoisted": 0,
+                                  "by_shape": {}, "arrivals_hoisted": 0,
                                   "arrivals_in_scan": 0}
         for name in SPANS:
             st[f"{name}_s"], st[f"{name}_n"] = 0.0, 0
@@ -623,6 +626,8 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
     stats["compile_s" if traced else "run_s"] += sp.seconds
     stats["dispatches"] += 1
     stats["horizon"] = int(cfg.delay_horizon_ticks)
+    shape = f"n{cfg.n_replicas}.d{stats['horizon']}"
+    stats["by_shape"][shape] = stats["by_shape"].get(shape, 0) + 1
     return PendingSweep(protocol, pts=pts, wl_names=wl_names, outs=outs,
                         n_real=len(pts) if mesh is not None else None)
 

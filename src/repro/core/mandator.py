@@ -75,8 +75,7 @@ def _tick(st: Dict, t: jax.Array, draw: jax.Array, env: Dict, cfg: SMRConfig,
           rate_per_tick: jax.Array, wlt: Dict | None,
           mode: workload.WorkloadMode) -> Dict:
     n = cfg.n_replicas
-    f = (n - 1) // 2
-    quorum = n - f
+    quorum = cfg.quorum
     alive = netsim.alive(env, t)
     delays = netsim.link_delay(env, t)
     drop = netsim.link_drop(env, t)

@@ -107,8 +107,7 @@ def tick(st: Dict, t: jax.Array, env: Dict, cfg: SMRConfig,
 def _tick(st: Dict, t: jax.Array, env: Dict, cfg: SMRConfig,
           lcr: jax.Array) -> Dict:
     n = cfg.n_replicas
-    f = (n - 1) // 2
-    q = n - f
+    q = cfg.quorum
     alive = netsim.alive(env, t)
     delays = netsim.link_delay(env, t).astype(jnp.int32)
     drop = netsim.link_drop(env, t)

@@ -72,12 +72,14 @@ def _compile_commit(spec: ch.RingSpec, d: int, n: int, sharding,
 
 
 @pytest.mark.parametrize("d", [256, 1024])
-def test_ring_commit_kernel_compiles_sporades_ring(one_chip, d):
-    """The Sporades ring (n = 5, K = 50) at the fig6 (256-slot) and
-    paper-ddos (1,024-slot) horizons, with one channel sent twice so the
-    kernel merges two max planes."""
-    n = 5
+@pytest.mark.parametrize("n", [5, 9])
+def test_ring_commit_kernel_compiles_sporades_ring(one_chip, n, d):
+    """The Sporades ring at five replicas (K = 50, rows padded 5 -> 8) and
+    at fig 9's nine (K = 78, rows padded 9 -> 16), at the fig6 (256-slot)
+    and paper-ddos (1,024-slot) horizons, with one channel sent twice so
+    the kernel merges two max planes."""
     spec = sporades.ring_spec(n)
+    assert spec.k == {5: 50, 9: 78}[n]
     names = [c.name for c in spec.channels] + ["vote"]
     hlo = _compile_commit(spec, d, n, one_chip, names)
     assert "tpu_custom_call" in hlo
@@ -93,11 +95,11 @@ def test_ring_commit_kernel_compiles_multipaxos_ring(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def _compile_canonical_ms(sharding):
-    """One whole canonical mandator-sporades sweep program (one lane, the
-    256-slot canonical ring) with the Pallas commit, compiled for
-    ``sharding``'s device."""
-    cfg = SMRConfig(sim_seconds=1.0, channel_backend="pallas")
+def _compile_canonical_ms(sharding, n: int = 5):
+    """One whole canonical mandator-sporades sweep program of ``n``
+    replicas (one lane, the 256-slot canonical ring) with the Pallas
+    commit, compiled for ``sharding``'s device."""
+    cfg = SMRConfig(n_replicas=n, sim_seconds=1.0, channel_backend="pallas")
     _, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = experiment._lower(
         cfg, SweepSpec(rates=(150_000,)))
     assert sig.lanes == 1 and sig.horizon == 256
@@ -110,13 +112,22 @@ def _compile_canonical_ms(sharding):
 
 @pytest.fixture(scope="module")
 def canonical_ms(one_chip):
-    return _compile_canonical_ms(one_chip)
+    """The canonical program of ``n`` replicas, compiled once a module."""
+    compiled = {}
+
+    def get(n: int = 5):
+        if n not in compiled:
+            compiled[n] = _compile_canonical_ms(one_chip, n)
+        return compiled[n]
+    return get
 
 
-def test_canonical_sporades_program_compiles(canonical_ms):
-    """The canonical mandator-sporades program compiles for one v5e chip
-    with the Pallas commit in it."""
-    compiled = canonical_ms
+@pytest.mark.parametrize("n", [5, 9])
+def test_canonical_sporades_program_compiles(canonical_ms, n):
+    """The canonical mandator-sporades program, at five replicas and at
+    fig 9's nine, compiles for one v5e chip with the Pallas commit in
+    it."""
+    compiled = canonical_ms(n)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
@@ -130,10 +141,11 @@ def _op_names(hlo: str):
     return re.findall(r'op_name="([^"]*)"', hlo)
 
 
-def test_named_scopes_reach_the_chip_program(canonical_ms):
+@pytest.mark.parametrize("n", [5, 9])
+def test_named_scopes_reach_the_chip_program(canonical_ms, n):
     """Every layer's named scope is in the compiled program's ``op_name``
     metadata, and the Pallas commit lies under ``ring_commit``."""
-    hlo = canonical_ms.as_text()
+    hlo = canonical_ms(n).as_text()
     comps = {c for name in _op_names(hlo) for c in name.split("/")}
     for scope in LAYER_SCOPES:
         assert scope in comps or f"vmap({scope})" in comps, scope
@@ -160,6 +172,6 @@ def test_named_scopes_add_no_instruction(canonical_ms, one_chip,
         return [re.sub(r"%[\w.\-]+", "%",
                        re.sub(r",? metadata=\{[^}]*\}", "", ln))
                 for ln in hlo.splitlines() if " = " in ln]
-    scoped = instructions(canonical_ms.as_text())
+    scoped = instructions(canonical_ms().as_text())
     assert len(scoped) > 1000
     assert scoped == instructions(bare)
