@@ -31,12 +31,17 @@ dominates total wall-clock (BENCH_core.json: >=95% of every fig suite), so
 ``run_sweep`` canonicalizes program *shapes* by default: the
 scenario/workload window tables round up to power-of-two floors, the
 auto-resolved ring horizon to ``netsim.CANONICAL_HORIZON``, and the
-program's batch width pins to ``CANONICAL_LANES`` (one lane) with the
-grid executed as per-point async dispatches of that one program. Every
-sweep with the same replica count, tick count, ring horizon, and
-workload mode — the fig 6/7/9 suites, the robustness and workload
-matrices, every ``run_sim`` single point — therefore reuses ONE compiled
-program per protocol instead of compiling per-suite shape variants.
+grid runs as async dispatches of chunks whose widths ``_lane_chunks``
+takes from the grid size, the target platform and the program's ring
+size: on the TPU, greedy powers of two of at most ``LANE_CAP`` lanes
+whose rings fit the VMEM the compiler keeps them in (a 20-point fig 6
+grid is two 8-lane launches and one 4-lane launch), elsewhere one
+``CANONICAL_LANES``-wide launch a point. No chunk is padded, and a
+one-point grid is the one-lane program everywhere. Every sweep with the
+same replica count, tick count, ring horizon, and workload mode — the
+fig 6/7/9 suites, the robustness and workload matrices, every
+``run_sim`` single point — therefore reuses one compiled program per
+protocol and chunk width instead of compiling per-suite shape variants.
 Canonicalization is inert by construction (vmap lanes are independent,
 pad window rows are never indexed, a larger ring never clips a valid
 delivery), and tests/test_scenarios.py pins canonical == native bitwise.
@@ -62,7 +67,7 @@ from __future__ import annotations
 
 import itertools
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Dict, Iterator, List, Tuple
 
@@ -78,15 +83,30 @@ from repro.kernels.channel_ring import ops as ring_ops
 
 ANALYTIC_PROTOCOLS = ("epaxos", "rabia")
 
-# Canonical program width: ONE lane. A canonical sweep executes its grid
-# as per-point dispatches of a single-lane compiled program, so a 1-point
-# run_sim, a 4-rate fig sweep, and a 16-cell robustness matrix all share
-# the same executable with zero padded (wasted) device work — padding the
-# batch axis instead was measured at up to 4x execution wall on
-# single-point sweeps. Window rows DO pad (rows are cheap: they are never
-# indexed past the real count) to a power-of-two floor so a baseline
-# (W=1) and a crash schedule (W=3) share one program.
+# Canonical program width of a one-point grid, and of every chunk off the
+# TPU: ONE lane. On the CPU a canonical sweep executes its grid as
+# per-point dispatches of the single-lane program, so a 1-point run_sim,
+# a 4-rate fig sweep, and a 16-cell robustness matrix share one
+# executable with zero padded (wasted) device work — padding the batch
+# axis instead was measured at up to 4x execution wall on single-point
+# sweeps. Window rows DO pad (rows are cheap: they are never indexed past
+# the real count) to a power-of-two floor so a baseline (W=1) and a crash
+# schedule (W=3) share one program.
 CANONICAL_LANES = 1
+# Widest chunk of a canonical grid on the TPU (a power of two; see
+# ``_lane_chunks``). A tick's ops work on arrays of a vector register or
+# two, so their time is set by their count more than their size: on one
+# v5e a point's device time falls from 210 ms at one lane to 100 ms a
+# lane at 8 lanes (fig 6's Mandator-Sporades; Multi-Paxos 97 -> 43 ms),
+# and 16 lanes were no faster (PERF.md, section 6: the width sweep).
+LANE_CAP = 8
+# Padded bytes of a chunk's packed rings (``ring_ops.tiled_ring_bytes``,
+# every lane, every ring) that the v5e compiler keeps in VMEM across the
+# tick scan: 80 MiB stay there (8 Mandator-Sporades lanes at 256 slots,
+# 2 at 1,024 or at n = 9), 144 MiB do not. A ring that falls out to HBM
+# makes the ring-commit kernel stream it every tick, 6-8x slower a lane
+# than in VMEM, so a chunk narrows until its rings fit.
+RING_VMEM_BYTES = 80 << 20
 # Window-table floor of 32 rows covers every library scenario and workload
 # at both --quick (2s) and full (4s) sim lengths (gray-wan tops out at 30
 # windows at 4s), so the fig suites AND the robustness matrix lower to the
@@ -111,7 +131,8 @@ class ProgramSignature:
     hit the same jit cache entry — zero new traces, zero new compiles."""
     n: int             # replicas
     ticks: int         # scan length (sim_seconds / tick_ms)
-    lanes: int         # compiled batch width (CANONICAL_LANES | grid size)
+    lanes: int         # compiled batch width: a chunk's (_lane_chunks),
+                       # or the grid size with canonical=False
     scen_windows: int  # scenario window-table rows (padded)
     wl_windows: int    # workload window-table rows (padded)
     horizon: int       # channel-ring slots (Dmax)
@@ -122,6 +143,33 @@ class ProgramSignature:
 def _canon_pow2(x: int, floor: int) -> int:
     """Next power of two >= x, floored at ``floor``."""
     return max(floor, 1 << (max(1, x) - 1).bit_length())
+
+
+def _lane_chunks(n_points: int, platform: str,
+                 lane_bytes: int = 0) -> List[int]:
+    """Widths of the chunks a canonical grid of ``n_points`` runs as, in
+    grid order. On the TPU: greedy powers of two of at most ``LANE_CAP``
+    lanes whose rings, ``lane_bytes`` a lane, fit ``RING_VMEM_BYTES``
+    (20 points at 8 lanes -> [8, 8, 4]), so no lane is padded and one
+    program serves each width. Elsewhere: one ``CANONICAL_LANES``-wide
+    chunk a point."""
+    if platform != "tpu":
+        return [CANONICAL_LANES] * n_points
+    cap = LANE_CAP
+    while cap > 1 and cap * lane_bytes > RING_VMEM_BYTES:
+        cap //= 2
+    chunks = []
+    while n_points:
+        chunks.append(min(cap, 1 << (n_points.bit_length() - 1)))
+        n_points -= chunks[-1]
+    return chunks
+
+
+def _lane_ring_bytes(protocol: str, cfg: SMRConfig) -> int:
+    """Padded bytes of one lane's packed rings on the TPU."""
+    n, d = cfg.n_replicas, int(cfg.delay_horizon_ticks)
+    return sum(ring_ops.tiled_ring_bytes(d, n, spec.k)
+               for spec in harness.ring_specs(protocol, n))
 
 
 def trace_counts() -> Dict[str, int]:
@@ -167,10 +215,14 @@ def timing_stats() -> Dict[str, Dict[str, float]]:
     other ``enqueue`` spans plus every ``readback`` span, which waits for
     execution), ``dispatches``, ``horizon`` (the resolved ring size
     of the latest sweep), ``by_shape`` (the dispatches by program shape,
-    keyed ``n{replicas}.d{horizon}``: one process may run several), and
-    ``arrivals_hoisted`` / ``arrivals_in_scan`` (the programs built, by
-    where they draw the arrivals)."""
-    return {k: {**v, "by_shape": dict(v["by_shape"])}
+    keyed ``n{replicas}.d{horizon}``: one process may run several),
+    ``by_lanes`` (the launches of unsharded programs by batch width,
+    keyed by the width as a string: a 16-point fig 6 grid on the TPU is
+    ``{"8": 2}``, on the CPU ``{"1": 16}``), and ``arrivals_hoisted``
+    / ``arrivals_in_scan`` (the programs built, by where they draw the
+    arrivals)."""
+    return {k: {**v, "by_shape": dict(v["by_shape"]),
+                "by_lanes": dict(v["by_lanes"])}
             for k, v in _TIMING.items()}
 
 
@@ -183,7 +235,8 @@ def _stats(protocol: str) -> Dict[str, float]:
     if st is None:
         st = _TIMING[protocol] = {"compile_s": 0.0, "run_s": 0.0,
                                   "dispatches": 0, "horizon": 0,
-                                  "by_shape": {}, "arrivals_hoisted": 0,
+                                  "by_shape": {}, "by_lanes": {},
+                                  "arrivals_hoisted": 0,
                                   "arrivals_in_scan": 0}
         for name in SPANS:
             st[f"{name}_s"], st[f"{name}_n"] = 0.0, 0
@@ -380,11 +433,11 @@ def _lower(cfg: SMRConfig, spec: SweepSpec, canonical: bool = True):
     canonical program signature: window tables pad to a power-of-two
     floor (pad rows are never indexed — ``win_of_tick`` only addresses
     real windows), the auto horizon rounds up to
-    ``netsim.CANONICAL_HORIZON``, and the program width is pinned to
-    ``CANONICAL_LANES`` — the grid then executes as per-point dispatches
-    of that one program (lanes are independent under vmap, so chunked
-    execution is bitwise identical to one wide dispatch; pinned in
-    tests)."""
+    ``netsim.CANONICAL_HORIZON``, and the signature's width is
+    ``CANONICAL_LANES`` — ``dispatch_sweep`` then runs the grid in chunks
+    of ``_lane_chunks`` widths (lanes are independent under vmap, so
+    chunked execution is bitwise identical to one wide dispatch; pinned
+    in tests)."""
     from repro import scenarios as sc
     pts = list(spec.points())
     # lower every scenario ONCE: the tables feed both the sweep-wide
@@ -410,7 +463,8 @@ def _lower(cfg: SMRConfig, spec: SweepSpec, canonical: bool = True):
           for f, t in zip(spec.scenarios, stabs)])
     cfg = netsim.resolve_horizon(cfg, tabs=stabs, canonical=canonical)
     # the stacks always hold every real point; ``lanes`` is the width of
-    # the compiled program (dispatch_sweep chunks the grid to fit)
+    # the native program, or of a one-point canonical chunk
+    # (dispatch_sweep chunks the grid)
     lane_pts = pts
     fidx = np.array([fi for _, _, fi, _ in lane_pts], np.int32)
     env_b = jax.tree.map(lambda x: x[fidx], stack)
@@ -545,9 +599,10 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
     compiled program. Analytic baselines (host loops) resolve eagerly.
 
     ``mesh`` selects the mesh-sharded engine: None (default) keeps the
-    legacy per-point dispatch loop; an int or a ``jax.sharding.Mesh``
-    with a ``("grid",)`` axis (see ``repro.distributed.mesh``) shards the
-    flattened grid's leading axis over the mesh devices as ONE dispatch,
+    chunked dispatch loop (one launch a ``_lane_chunks`` chunk); an int
+    or a ``jax.sharding.Mesh`` with a ``("grid",)`` axis (see
+    ``repro.distributed.mesh``) shards the flattened grid's leading axis
+    over the mesh devices as ONE dispatch,
     each device scanning its grid slice with the same canonical
     single-lane point program and reducing metrics on device to a
     fixed-size latency sketch (``harness.sim_point(reduced=True)``).
@@ -584,40 +639,46 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
             env_b = jax.tree.map(lambda x: x[idx], env_b)
             wl_b = jax.tree.map(lambda x: x[idx], wl_b)
             rate_b, seed_b = rate_b[idx], seed_b[idx]
-    # the sharded path registers the SAME canonical signature — the point
-    # computation (and so the persistent-cache key material) is unchanged;
-    # only the orchestration around it is
-    _SIGNATURES.setdefault(protocol, set()).add(sig)
     if mesh is not None:
+        # the sharded path registers the SAME canonical signature — the
+        # point computation (and so the persistent-cache key material) is
+        # unchanged; only the orchestration around it is
+        _SIGNATURES.setdefault(protocol, set()).add(sig)
         _SHARD_SIGNATURES.setdefault(protocol, set()).add(
             (sig, int(mesh.devices.size)))
+        widths = []
+    else:
+        # canonical: chunks of ``_lane_chunks`` widths, launched async in
+        # grid order (lanes are independent under vmap, so this is
+        # bitwise identical to one wide dispatch); native: one launch
+        widths = (_lane_chunks(len(pts), ring_ops.target_platform(),
+                               _lane_ring_bytes(protocol, cfg))
+                  if canonical else [sig.lanes])
+        _SIGNATURES.setdefault(protocol, set()).update(
+            replace(sig, lanes=w) for w in widths)
     traces_before = _TRACE_COUNTS.get(protocol, 0)
     with _span(protocol, "enqueue") as sp:
         if mesh is not None:
             fn = _acquire_sharded(protocol, cfg, mode, mesh)
             outs = [fn(env_b, wl_b, rate_b, seed_b)]
         else:
-            if sig.lanes == len(pts):
-                chunks = [(env_b, wl_b, rate_b, seed_b)]
-            else:
-                # canonical: the grid runs as per-point async dispatches of
-                # the shared ``CANONICAL_LANES``-wide program (lanes are
-                # independent under vmap, so this is bitwise identical to
-                # one wide dispatch)
-                chunks = [(jax.tree.map(lambda x: x[i:i + 1], env_b),
-                           jax.tree.map(lambda x: x[i:i + 1], wl_b),
-                           rate_b[i:i + 1], seed_b[i:i + 1])
-                          for i in range(len(pts))]
-            if canonical:
-                # canonical programs additionally go through the on-disk
-                # program store: warm processes deserialize the traced
-                # computation instead of re-tracing it (the persistent XLA
-                # cache below then supplies the executable)
-                prog = _acquire_program(protocol, cfg, mode, chunks[0])
-                outs = [prog(*c) for c in chunks]
-            else:
-                outs = [_sweep_compiled(protocol, cfg, mode, *c)
-                        for c in chunks]
+            outs, progs, lo = [], {}, 0
+            for w in widths:
+                # host-side numpy slices: free
+                chunk = jax.tree.map(lambda x: x[lo:lo + w],
+                                     (env_b, wl_b, rate_b, seed_b))
+                lo += w
+                if w not in progs:
+                    # canonical programs additionally go through the
+                    # on-disk program store: warm processes deserialize
+                    # the traced computation instead of re-tracing it (the
+                    # persistent XLA cache below then supplies the
+                    # executable); each width is a program of its own
+                    progs[w] = (
+                        _acquire_program(protocol, cfg, mode, chunk)
+                        if canonical
+                        else partial(_sweep_compiled, protocol, cfg, mode))
+                outs.append(progs[w](*chunk))
     stats = _stats(protocol)
     # dispatch returns before the device finishes: an enqueue that traced
     # is pure trace + lower + (backend compile | cache load); collect()
@@ -628,6 +689,8 @@ def dispatch_sweep(protocol: str, cfg: SMRConfig, spec: SweepSpec,
     stats["horizon"] = int(cfg.delay_horizon_ticks)
     shape = f"n{cfg.n_replicas}.d{stats['horizon']}"
     stats["by_shape"][shape] = stats["by_shape"].get(shape, 0) + 1
+    for w in widths:
+        stats["by_lanes"][str(w)] = stats["by_lanes"].get(str(w), 0) + 1
     return PendingSweep(protocol, pts=pts, wl_names=wl_names, outs=outs,
                         n_real=len(pts) if mesh is not None else None)
 

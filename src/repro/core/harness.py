@@ -17,7 +17,7 @@ single-point wrapper over that engine, kept for backward compatibility.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +80,19 @@ def _closed_feedback(protocol: str, carry: Dict, out: Dict) -> Dict:
     return carry
 
 
+def ring_specs(protocol: str, n: int) -> List[ch.RingSpec]:
+    """The packed rings a point of ``protocol`` carries, in carry order
+    (Mandator's ``m``, then Sporades' ``s`` or Paxos' ``p``)."""
+    specs = []
+    if protocol in ("mandator-sporades", "mandator-paxos", "mandator"):
+        specs.append(mandator.ring_spec())
+    if protocol == "mandator-sporades":
+        specs.append(sporades.ring_spec(n))
+    if protocol in ("mandator-paxos", "multipaxos"):
+        specs.append(paxos.ring_spec(n, protocol == "mandator-paxos"))
+    return specs
+
+
 def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
     """Protocol-state projection the health monitor consumes
     (repro.obs.monitor.update): per-replica committed vector clocks /
@@ -91,11 +104,9 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
     ``mon_io``."""
     n = cfg.n_replicas
     views: Dict = {"cvc": None, "commit_seq": None, "view": None}
-    rings = []
     dropped = jnp.zeros((n,), jnp.int32)
     if protocol in ("mandator-sporades", "mandator-paxos", "mandator"):
         m = carry["m"]
-        rings.append((mandator.ring_spec(), m["ring"]))
         dropped = dropped + m["mon_io"]["dropped"]
         views["formed"] = m["formed_round"]
         views["stable"] = m["own_round"]
@@ -110,7 +121,6 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
             m["formed_round"] > m["own_round"])
     elif protocol == "mandator-sporades":
         s = carry["s"]
-        rings.append((sporades.ring_spec(n), s["ring"]))
         dropped = dropped + s["mon_io"]["dropped"]
         views["cvc"] = s["cvc"]
         views["commit_seq"] = s["commit_key"]
@@ -120,7 +130,6 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
             m["formed_round"] > jnp.max(s["cvc"], axis=0))
     elif protocol == "mandator-paxos":
         p = carry["p"]
-        rings.append((paxos.ring_spec(n, True), p["ring"]))
         dropped = dropped + p["mon_io"]["dropped"]
         views["cvc"] = p["cvc"]
         views["view"] = p["view"]
@@ -129,7 +138,6 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
             m["formed_round"] > jnp.max(p["cvc"], axis=0))
     elif protocol == "multipaxos":
         p = carry["p"]
-        rings.append((paxos.ring_spec(n, False), p["ring"]))
         dropped = dropped + p["mon_io"]["dropped"]
         # per-replica slot counters are each leader's own ledger: formed
         # (last started) vs stable (last committed) per replica
@@ -141,7 +149,9 @@ def _monitor_views(protocol: str, cfg: SMRConfig, carry: Dict) -> Dict:
             p["committed_slot"]).astype(jnp.float32)
         views["pending"] = (jnp.sum(p["wl"]["buffer"]) > 0) \
             | jnp.any(p["outstanding"])
-    occ = [ch.ring_occupancy(spec, ring) for spec, ring in rings]
+    rings = [carry[k]["ring"] for k in ("m", "s", "p") if k in carry]
+    occ = [ch.ring_occupancy(spec, ring)
+           for spec, ring in zip(ring_specs(protocol, n), rings)]
     views["ring_occ"] = occ[0] if len(occ) == 1 else jnp.maximum(*occ)
     views["dropped"] = dropped
     return views

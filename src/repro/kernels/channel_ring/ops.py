@@ -41,6 +41,13 @@ def target_platform() -> str:
     return dev if isinstance(dev, str) else dev.platform
 
 
+def tiled_ring_bytes(d: int, n: int, k: int) -> int:
+    """Bytes of one lane's ``[d, n, n, k]`` f32 ring as the TPU lays it
+    out, its last two axes in (8, 128) tiles: what the compiler weighs
+    when it keeps a scan's ring in VMEM (``experiment.RING_VMEM_BYTES``)."""
+    return d * n * (-(-n // 8) * 8) * (-(-k // 128) * 128) * 4
+
+
 def resolve_backend(backend: str) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"unknown channel backend {backend!r}; "

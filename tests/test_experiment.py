@@ -2,6 +2,7 @@
 compile at most once per protocol (never per grid point) and produce
 bitwise-identical metrics to the equivalent sequence of single run_sim
 calls (same seeds/scenarios)."""
+import jax
 import numpy as np
 import pytest
 
@@ -109,3 +110,80 @@ def test_host_spans_are_annotations_on_the_profiler_clock(tmp_path):
                     if e.name.startswith("experiment."))
     assert [n for _, n in events] == [f"experiment.{s}"
                                       for s in experiment.SPANS]
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("n", [1, 2, 3, 16, 17, 20, 256])
+def test_lane_chunks_split_the_grid(n, platform):
+    """On the TPU a canonical grid runs as chunks of non-increasing powers
+    of two, each at most ``LANE_CAP``, that cover it with no padding;
+    elsewhere as one one-lane chunk a point."""
+    chunks = experiment._lane_chunks(n, platform)
+    assert sum(chunks) == n
+    if platform == "cpu":
+        assert chunks == [1] * n
+        return
+    assert all(c & (c - 1) == 0 and 1 <= c <= experiment.LANE_CAP
+               for c in chunks)
+    assert chunks == sorted(chunks, reverse=True)
+    assert chunks[0] == min(experiment.LANE_CAP, 1 << (n.bit_length() - 1))
+
+
+@pytest.mark.parametrize("protocol,n,horizon,width", [
+    ("mandator-sporades", 5, 256, 8), ("multipaxos", 5, 256, 8),
+    ("mandator-sporades", 5, 1024, 2), ("multipaxos", 5, 1024, 4),
+    ("mandator-sporades", 9, 256, 2)])
+def test_lane_chunks_keep_a_chunks_rings_in_vmem(protocol, n, horizon,
+                                                 width):
+    """On the TPU a chunk narrows from ``LANE_CAP`` until its padded rings
+    fit ``RING_VMEM_BYTES``: fig 6's programs keep 8 lanes, the 1,024-slot
+    and the nine-replica ones fewer."""
+    cfg = SMRConfig(n_replicas=n, delay_horizon_ticks=horizon)
+    lane = experiment._lane_ring_bytes(protocol, cfg)
+    assert experiment._lane_chunks(64, "tpu", lane) == [width] * (64 // width)
+    assert width * lane <= experiment.RING_VMEM_BYTES
+    assert (width == experiment.LANE_CAP
+            or 2 * width * lane > experiment.RING_VMEM_BYTES)
+
+
+def _assert_rows_bitwise(wide, narrow):
+    assert len(wide) == len(narrow)
+    for w, s in zip(wide, narrow):
+        assert w.keys() == s.keys()
+        for k in w:
+            lw, ls = jax.tree.leaves(w[k]), jax.tree.leaves(s[k])
+            assert len(lw) == len(ls), k
+            for a, b in zip(lw, ls):
+                a, b = np.asarray(a), np.asarray(b)
+                assert (a.dtype, a.shape) == (b.dtype, b.shape), k
+                assert a.tobytes() == b.tobytes(), k
+
+
+@pytest.mark.parametrize("protocol", ["mandator-sporades", "multipaxos"])
+def test_multi_lane_chunks_match_one_lane_bitwise(protocol, monkeypatch):
+    """A 6-point grid run as a 4-lane and a 2-lane launch (the TPU's
+    chunking, forced here) equals its one-lane-a-point run in every output
+    of every point, bit for bit, with one program built per width (0.6 s
+    simulated: both protocols commit at every rate by then)."""
+    cfg = SMRConfig(sim_seconds=0.6)
+    spec = SweepSpec(rates=(20_000, 60_000, 120_000), seeds=(3, 4))
+    experiment.reset_timing_stats()
+    narrow = run_sweep(protocol, cfg, spec)
+    assert experiment.timing_stats()[protocol]["by_lanes"] == {"1": 6}
+
+    def chunks(n_points, platform, lane_bytes):
+        assert n_points == spec.size
+        return [4, 2]
+    monkeypatch.setattr(experiment, "_lane_chunks", chunks)
+    experiment.reset_trace_counts()
+    experiment.reset_timing_stats()
+    wide = run_sweep(protocol, cfg, spec)
+    assert experiment.trace_counts()[protocol] == 2
+    assert experiment.timing_stats()[protocol]["by_lanes"] == {"4": 1,
+                                                               "2": 1}
+    assert sorted(sig.lanes for sig in
+                  experiment.program_signatures()[protocol]) == [2, 4]
+    assert all(r["committed"] > 0 for r in narrow)
+    if protocol == "mandator-sporades":
+        assert "cvc_all" in wide[0] and "commit_key" in wide[0]
+    _assert_rows_bitwise(wide, narrow)

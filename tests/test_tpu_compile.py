@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.smr import SMRConfig
 from repro.core import channel as ch
-from repro.core import experiment, paxos, sporades
+from repro.core import experiment, mandator, paxos, sporades
 from repro.core.experiment import SweepSpec
 
 pytestmark = pytest.mark.no_persistent_cache
@@ -95,15 +95,18 @@ def test_ring_commit_kernel_compiles_multipaxos_ring(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def _compile_canonical_ms(sharding, n: int = 5):
+def _compile_canonical_ms(sharding, n: int = 5, lanes: int = 1,
+                          horizon: int = 256):
     """One whole canonical mandator-sporades sweep program of ``n``
-    replicas (one lane, the 256-slot canonical ring) with the Pallas
-    commit, compiled for ``sharding``'s device."""
-    cfg = SMRConfig(n_replicas=n, sim_seconds=1.0, channel_backend="pallas")
+    replicas (``lanes`` wide, rings of ``horizon`` slots: 256 is the
+    canonical ring) with the Pallas commit, compiled for ``sharding``'s
+    device."""
+    cfg = SMRConfig(n_replicas=n, sim_seconds=1.0, channel_backend="pallas",
+                    delay_horizon_ticks=horizon)
     _, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = experiment._lower(
-        cfg, SweepSpec(rates=(150_000,)))
-    assert sig.lanes == 1 and sig.horizon == 256
-    args = jax.tree.map(lambda x: _shape(x[:1], sharding),
+        cfg, SweepSpec(rates=(150_000,), seeds=tuple(range(lanes))))
+    assert sig.lanes == 1 and sig.horizon == horizon
+    args = jax.tree.map(lambda x: _shape(x[:lanes], sharding),
                         (env_b, wl_b, rate_b, seed_b))
     fn = jax.jit(partial(experiment._sweep_body, "mandator-sporades", cfg,
                          mode))
@@ -131,6 +134,29 @@ def test_canonical_sporades_program_compiles(canonical_ms, n):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("horizon", [256, 1024])
+def test_grid_chunk_program_keeps_its_rings_in_vmem(one_chip, horizon):
+    """The multi-lane program a 16-point grid runs as on the TPU (8 lanes
+    at fig 6's 256 slots, 2 at the DDoS horizon's 1,024) compiles for one
+    v5e chip with the Pallas commit batched over its lanes, in well under
+    the chip's memory, and the compiler keeps both packed rings in VMEM
+    (memory space ``S(1)``) across the scan: the width rule's premise."""
+    cfg = SMRConfig(sim_seconds=1.0, delay_horizon_ticks=horizon)
+    (lanes,) = set(experiment._lane_chunks(
+        16, "tpu", experiment._lane_ring_bytes("mandator-sporades", cfg)))
+    assert lanes > 1
+    compiled = _compile_canonical_ms(one_chip, lanes=lanes, horizon=horizon)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    for spec in (mandator.ring_spec(), sporades.ring_spec(5)):
+        layouts = set(re.findall(
+            rf"f32\[{lanes},{horizon},5,5,{spec.k}\]\{{([^}}]*)\}}", hlo))
+        tiled = [lay for lay in layouts if "T(8,128)" in lay]
+        assert tiled and all("S(1)" in lay for lay in tiled), (spec.k,
+                                                                layouts)
 
 
 LAYER_SCOPES = ("arrivals", "ring_deliver", "ring_commit", "mandator",
