@@ -1,7 +1,6 @@
-"""Kernel micro-benchmarks: wall time of the pure-jnp oracle path on CPU
-(the Pallas kernels target TPU; interpret-mode timing is not meaningful, so
-we time the XLA fallback the models actually run on this host and record
-the kernels' analytic VMEM working sets as `derived`)."""
+"""Channel-ring micro-benchmark: wall time of the packed ring's tick loop
+against the seed per-channel substrate, on whatever backend JAX resolves
+(the Pallas ring commit on a TPU, the pure-jnp scatter oracle elsewhere)."""
 from __future__ import annotations
 
 import time
@@ -20,53 +19,6 @@ def _time(fn, *args, reps: int = 5) -> float:
     for _ in range(reps):
         jax.block_until_ready(fn(*args))
     return (time.perf_counter() - t0) / reps * 1e6
-
-
-def bench() -> List[Row]:
-    from repro.core import compile_cache
-    compile_cache.ensure()   # microbench compiles hit the persistent cache
-    rows: List[Row] = []
-    key = jax.random.PRNGKey(0)
-
-    # flash attention (chunked jnp path vs dense)
-    from repro.models.layers import chunked_attention, dense_attention
-    b, s, h, kh, d = 1, 1024, 8, 4, 64
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, kh, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, kh, d), jnp.float32)
-    f_dense = jax.jit(lambda q, k, v: dense_attention(q, k, v, causal=True))
-    f_chunk = jax.jit(lambda q, k, v: chunked_attention(q, k, v, causal=True,
-                                                        chunk=128))
-    t1 = _time(f_dense, q, k, v)
-    t2 = _time(f_chunk, q, k, v)
-    vmem_kb = (128 * d * 2 * 2 + 128 * 128 * 4) / 1024
-    rows.append(("kernel/attention_dense_1k", t1, f"impl=dense;s={s}"))
-    rows.append(("kernel/attention_flash_1k", t2,
-                 f"impl=chunked;s={s};kernel_vmem_kb={vmem_kb:.0f}"))
-
-    # rmsnorm fused
-    from repro.kernels.rmsnorm.ref import rmsnorm_ref
-    x = jax.random.normal(key, (4096, 1024))
-    w = jnp.ones((1024,))
-    f_norm = jax.jit(lambda x, w: rmsnorm_ref(x, w))
-    rows.append(("kernel/rmsnorm_4096x1024", _time(f_norm, x, w),
-                 "bytes_per_row=8192"))
-
-    # ssm scan (chunked jnp path == what the dry run lowers)
-    from repro.models.ssm import mamba_ssm
-    bt, st_, di, n = 1, 2048, 512, 16
-    ks = jax.random.split(key, 6)
-    xs = jax.random.normal(ks[0], (bt, st_, di)) * 0.3
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (bt, st_, di)) - 1)
-    B = jax.random.normal(ks[2], (bt, st_, n))
-    C = jax.random.normal(ks[3], (bt, st_, n))
-    A = -jnp.exp(jax.random.normal(ks[4], (di, n)) * 0.3)
-    D = jax.random.normal(ks[5], (di,))
-    f_ssm = jax.jit(lambda *a: mamba_ssm(*a, chunk=128))
-    rows.append(("kernel/ssm_scan_2048x512", _time(f_ssm, xs, dt, B, C, A, D),
-                 f"state_vmem_kb={256*n*4/1024:.0f}"))
-    return rows
 
 
 def _channel_setup(n: int = 5):

@@ -1,65 +1,10 @@
-"""§Roofline report: per (arch x shape) terms from the dry-run artifacts.
-
-Reads benchmarks/artifacts/dryrun/*.json (produced by repro.launch.dryrun),
-emits the single-pod roofline table (+ the multi-pod compile check) as
-markdown + CSV rows. Hardware constants: TPU v5e — 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI (distributed/hlo_analysis.py).
+"""HLO cost and roofline blocks of the simulator's programs: the packed
+channel loop the ``channel`` suite times, and the canonical one-lane sweep
+program of the ``scaling`` suite. Hardware constants: TPU v5e — 197
+TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
+(distributed/hlo_analysis.py).
 """
 from __future__ import annotations
-
-import json
-from pathlib import Path
-from typing import List, Tuple
-
-ART = Path(__file__).resolve().parent / "artifacts"
-DRY = ART / "dryrun"
-
-Row = Tuple[str, float, str]
-
-
-def load(mesh: str) -> List[dict]:
-    out = []
-    for p in sorted(DRY.glob(f"*__{mesh}.json")):
-        out.append(json.loads(p.read_text()))
-    return out
-
-
-def table(mesh: str = "single") -> str:
-    recs = load(mesh)
-    lines = [
-        "| arch | shape | compute (ms) | memory (ms) | collective (ms) | "
-        "dominant | MODEL_FLOPS/HLO | bound (ms) | note |",
-        "|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in recs:
-        if "skipped" in r:
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | — | — "
-                         f"| — | skipped: full-attention (no sub-quadratic "
-                         f"path) |")
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['compute_s']*1e3:.1f} | "
-            f"{r['memory_s']*1e3:.1f} | {r['collective_s']*1e3:.1f} | "
-            f"{r['dominant']} | {r['useful_flop_ratio']:.2f} | "
-            f"{r['bound_s']*1e3:.1f} | |")
-    return "\n".join(lines)
-
-
-def rows(mesh: str = "single") -> List[Row]:
-    out: List[Row] = []
-    for r in load(mesh):
-        if "skipped" in r:
-            out.append((f"roofline/{r['arch']}/{r['shape']}/{mesh}", 0.0,
-                        "skipped=1"))
-            continue
-        out.append((
-            f"roofline/{r['arch']}/{r['shape']}/{mesh}",
-            r["bound_s"] * 1e6,
-            f"dominant={r['dominant']};compute_ms={r['compute_s']*1e3:.2f};"
-            f"memory_ms={r['memory_s']*1e3:.2f};"
-            f"collective_ms={r['collective_s']*1e3:.2f};"
-            f"useful={r['useful_flop_ratio']:.3f}"))
-    return out
 
 
 def channel_hlo_block(dmax: int = 256, ticks: int = 200) -> dict:
@@ -147,29 +92,3 @@ def sweep_hlo_block(sim_seconds: float = 0.25,
                          "byte_share": round(d["bytes"] / total, 4)}
                         for k, d in top],
     }
-
-
-def summary(mesh: str = "single") -> dict:
-    recs = [r for r in load(mesh) if "skipped" not in r]
-    doms = {}
-    for r in recs:
-        doms[r["dominant"]] = doms.get(r["dominant"], 0) + 1
-    return {"cells": len(recs), "dominant_counts": doms,
-            "mean_useful": sum(r["useful_flop_ratio"] for r in recs)
-            / max(len(recs), 1)}
-
-
-def main() -> None:
-    for mesh in ("single", "multi"):
-        recs = load(mesh)
-        if not recs:
-            continue
-        md = table(mesh)
-        (ART / f"roofline_{mesh}.md").write_text(md)
-        print(f"# roofline ({mesh}): {len(recs)} cells -> "
-              f"{ART}/roofline_{mesh}.md")
-        print(json.dumps(summary(mesh), indent=1))
-
-
-if __name__ == "__main__":
-    main()

@@ -1,6 +1,6 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``.
 """Benchmark driver:
-  PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig6,roofline,...]
+  PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig6,channel,...]
       [--no-compile-cache]
 
 Figure suites dispatch through the batched experiment engine
@@ -20,7 +20,7 @@ wall-clock at millisecond precision, the compile-vs-run split, the
 compile-accounting fields (jit traces, distinct program signatures,
 persistent-cache hits/misses, true backend-compile seconds), and the
 resolved channel-ring horizon — so the perf trajectory is tracked across
-PRs. Microbench suites (channel/kernels) get their compile/run split from
+PRs. The microbench suite (channel) gets its compile/run split from
 the jax.monitoring backend-compile counters instead of the sweep engine's
 dispatch timers. The ``channel`` suite's packed-vs-legacy comparison lands
 in ``benchmarks/artifacts/channel_bench.json``.
@@ -37,7 +37,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from benchmarks import figures  # noqa: E402
 from benchmarks import roofline  # noqa: E402
-from benchmarks.bench_kernels import bench as kernel_bench  # noqa: E402
 from benchmarks.bench_kernels import bench_channel  # noqa: E402
 from repro.core import compile_cache, experiment  # noqa: E402
 from repro.obs import history  # noqa: E402
@@ -168,10 +167,7 @@ def main() -> None:
         "workload-matrix": lambda: figures.workload_matrix(sim_s),
         "scaling": lambda: _scaling_suite(args.quick),
         "paper": figures.paper_comparison,
-        "kernels": kernel_bench,
         "channel": _channel_suite,
-        "roofline_single": lambda: roofline.rows("single"),
-        "roofline_multi": lambda: roofline.rows("multi"),
         "hlo-audit": lambda: _hlo_audit_suite(sim_s),
     }
     if only:
@@ -286,7 +282,6 @@ def main() -> None:
         cmp_res = history.compare(base, entry)
         for line in history.format_compare(cmp_res):
             print(f"# history: {line}", file=sys.stderr)
-    roofline.main()
     if errored:
         sys.exit(f"suite(s) errored: {', '.join(errored)}")
 

@@ -1,3 +1,3 @@
-"""repro — Mandator & Sporades as a multi-pod JAX training/serving framework."""
+"""repro — Mandator & Sporades as a tick-level WAN-consensus simulator in JAX."""
 
 __version__ = "1.0.0"

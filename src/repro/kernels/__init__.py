@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# The one custom kernel: channel_ring/ (kernel.py + ops.py + ref.py), the
+# fused ring commit every protocol's tick runs (Pallas on the TPU, the
+# pure-jnp scatter oracle elsewhere).

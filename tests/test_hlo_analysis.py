@@ -1,5 +1,5 @@
 """Unit tests for ``repro.distributed.hlo_analysis`` on a fixture HLO
-module (pure text — no jax): the loop-aware cost model, the async
+module: the loop-aware cost model, the async
 ``-start``/``-done`` opcode handling, parser hardening (tuple results,
 nested tuples, fusion calls, while bodies with ``known_trip_count``),
 and the program-audit queries behind ``repro.analysis.hlo_lint``.
@@ -8,9 +8,14 @@ The fixture is a hand-written module with one scan-shaped while loop
 (trip count 10) containing an async all-gather and a python-callback
 custom-call, plus entry-level dots (one direct, one fused), an f64
 convert, and a host-buffer custom-call OUTSIDE the loop — so every
-audit query has both a positive and a negative case.
+audit query has both a positive and a negative case. The last two tests
+check the cost model on a compiled ``lax.scan`` and the collective parser
+on a hand-written module.
 """
 from pathlib import Path
+
+import jax
+import jax.numpy as jnp
 
 from repro.distributed import hlo_analysis as hlo
 
@@ -97,3 +102,36 @@ def test_host_transfer_ops_tag_loop_membership():
     assert set(ops) == {"cb", "hcb"}
     assert ops["cb"]["in_loop"] is True      # H2's positive case
     assert ops["hcb"]["in_loop"] is False    # post-scan host pull: legal
+
+
+def test_hlo_cost_model_counts_scan_trips():
+    from repro.distributed.hlo_analysis import module_cost
+
+    def f(a, b):
+        def body(x, _):
+            return x @ b, None
+        out, _ = jax.lax.scan(body, a, None, length=10)
+        return out
+
+    s = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    txt = jax.jit(f).lower(s, s).compile().as_text()
+    mc = module_cost(txt)
+    expect = 10 * 2 * 256 ** 3
+    assert abs(mc["flops"] - expect) / expect < 0.05
+
+
+def test_hlo_collective_parsing_fixture():
+    from repro.distributed.hlo_analysis import collective_stats
+    fake = """
+HloModule m
+
+ENTRY %main (p0: f32[16,128]) -> f32[16,128] {
+  %p0 = f32[16,128]{1,0} parameter(0)
+  %ar = f32[16,128]{1,0} all-reduce(%p0), to_apply=%add
+  ROOT %ag = f32[32,128]{1,0} all-gather(%ar), dimensions={0}
+}
+"""
+    st_ = collective_stats(fake)
+    assert st_["all-reduce"]["count"] == 1
+    assert st_["all-reduce"]["bytes"] == 16 * 128 * 4
+    assert st_["all-gather"]["bytes"] == 32 * 128 * 4

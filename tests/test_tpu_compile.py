@@ -3,8 +3,8 @@
 The TPU compiler is installed next to the CPU backend, and compiles for a
 topology that is described and not attached. It refuses what the Pallas
 interpreter accepts (scatters and unaligned lane slices in a kernel body,
-VMEM overruns), so these tests pin that the ring-commit kernel and one
-whole canonical sweep program lower to a ``tpu_custom_call`` for v5e.
+VMEM overruns), so these tests pin that the ring-commit kernel and the
+whole canonical sweep programs lower to a ``tpu_custom_call`` for v5e.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import workloads as wl
 from repro.configs.smr import SMRConfig
 from repro.core import channel as ch
 from repro.core import experiment, mandator, paxos, sporades
@@ -95,22 +96,38 @@ def test_ring_commit_kernel_compiles_multipaxos_ring(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def _compile_canonical_ms(sharding, n: int = 5, lanes: int = 1,
-                          horizon: int = 256):
-    """One whole canonical mandator-sporades sweep program of ``n``
+def _compile_canonical(sharding, protocol: str = "mandator-sporades",
+                       n: int = 5, lanes: int = 1, horizon: int = 256,
+                       closed_loop: bool = False):
+    """One whole canonical sweep program of ``protocol`` at ``n``
     replicas (``lanes`` wide, rings of ``horizon`` slots: 256 is the
-    canonical ring) with the Pallas commit, compiled for ``sharding``'s
-    device."""
+    canonical ring; open-loop Poisson clients, or ``ClosedLoop`` pools)
+    with the Pallas commit, compiled for ``sharding``'s device."""
     cfg = SMRConfig(n_replicas=n, sim_seconds=1.0, channel_backend="pallas",
                     delay_horizon_ticks=horizon)
+    traffic = {"workloads": (wl.Workload("closed-loop", (
+        wl.ClosedLoop(),)),)} if closed_loop else {}
     _, cfg, mode, env_b, wl_b, rate_b, seed_b, sig = experiment._lower(
-        cfg, SweepSpec(rates=(150_000,), seeds=tuple(range(lanes))))
+        cfg, SweepSpec(rates=(150_000,), seeds=tuple(range(lanes)),
+                       **traffic))
     assert sig.lanes == 1 and sig.horizon == horizon
+    assert mode.closed == closed_loop
     args = jax.tree.map(lambda x: _shape(x[:lanes], sharding),
                         (env_b, wl_b, rate_b, seed_b))
-    fn = jax.jit(partial(experiment._sweep_body, "mandator-sporades", cfg,
-                         mode))
+    fn = jax.jit(partial(experiment._sweep_body, protocol, cfg, mode))
     return fn.lower(*args).compile()
+
+
+def _rings_in_vmem(hlo: str, specs, n: int, lanes: int, horizon: int):
+    """Each packed ring's tiled buffers in ``hlo`` sit in VMEM (memory
+    space ``S(1)``)."""
+    for spec in specs:
+        layouts = set(re.findall(
+            rf"f32\[{lanes},{horizon},{n},{n},{spec.k}\]\{{([^}}]*)\}}",
+            hlo))
+        tiled = [lay for lay in layouts if "T(8,128)" in lay]
+        assert tiled and all("S(1)" in lay for lay in tiled), (spec.k,
+                                                                layouts)
 
 
 @pytest.fixture(scope="module")
@@ -120,7 +137,7 @@ def canonical_ms(one_chip):
 
     def get(n: int = 5):
         if n not in compiled:
-            compiled[n] = _compile_canonical_ms(one_chip, n)
+            compiled[n] = _compile_canonical(one_chip, n=n)
         return compiled[n]
     return get
 
@@ -147,16 +164,34 @@ def test_grid_chunk_program_keeps_its_rings_in_vmem(one_chip, horizon):
     (lanes,) = set(experiment._lane_chunks(
         16, "tpu", experiment._lane_ring_bytes("mandator-sporades", cfg)))
     assert lanes > 1
-    compiled = _compile_canonical_ms(one_chip, lanes=lanes, horizon=horizon)
+    compiled = _compile_canonical(one_chip, lanes=lanes, horizon=horizon)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    for spec in (mandator.ring_spec(), sporades.ring_spec(5)):
-        layouts = set(re.findall(
-            rf"f32\[{lanes},{horizon},5,5,{spec.k}\]\{{([^}}]*)\}}", hlo))
-        tiled = [lay for lay in layouts if "T(8,128)" in lay]
-        assert tiled and all("S(1)" in lay for lay in tiled), (spec.k,
-                                                                layouts)
+    _rings_in_vmem(hlo, (mandator.ring_spec(), sporades.ring_spec(5)), 5,
+                   lanes, horizon)
+
+
+@pytest.mark.parametrize("n", [5, 9])
+def test_canonical_multipaxos_program_compiles(one_chip, n):
+    """The whole canonical Multi-Paxos program, at five replicas and at
+    nine, compiles for one v5e chip with the Pallas commit in it, and the
+    compiler keeps its one packed ring (with the add-merged ``fw``
+    channel) in VMEM at the canonical 256 slots."""
+    compiled = _compile_canonical(one_chip, "multipaxos", n=n)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    _rings_in_vmem(hlo, (paxos.ring_spec(n, False),), n, 1, 256)
+
+
+def test_closed_loop_sporades_program_compiles(one_chip):
+    """The closed-loop Mandator-Sporades program (``ClosedLoop`` clients:
+    the in-flight count rides the scan carry and falls as batches commit)
+    compiles for one v5e chip with the Pallas commit in it."""
+    compiled = _compile_canonical(one_chip, closed_loop=True)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 LAYER_SCOPES = ("arrivals", "ring_deliver", "ring_commit", "mandator",
@@ -191,7 +226,7 @@ def test_named_scopes_add_no_instruction(canonical_ms, one_chip,
     differ (the lowering numbers ops as it emits them)."""
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    bare = _compile_canonical_ms(one_chip).as_text()
+    bare = _compile_canonical(one_chip).as_text()
     assert "/ring_commit/" not in bare
 
     def instructions(hlo):
